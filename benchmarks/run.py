@@ -20,6 +20,7 @@ from benchmarks import (ablations, adaptive, analyzer_pruning, batch_mode,
                         cache_hit, feedback, load_aware, merging,
                         obs_overhead, roofline, router_scale, routing_win,
                         soak)
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = {
     "routing_win": routing_win.run,
@@ -77,6 +78,7 @@ def main(argv=None) -> int:
                     help="seconds-scale CI variants (subset of "
                     f"{sorted(SMOKE)})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         names = args.only or list(SMOKE)
         missing = [n for n in names if n not in SMOKE]

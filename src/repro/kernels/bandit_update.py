@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _bandit_update_kernel(w_ref, xx_ref, xr_ref, xs_ref, xxs_ref,
                           theta_ref, ainv_ref, da_ref, db_ref, ucb_ref):
@@ -68,7 +66,7 @@ def bandit_update_pallas(w: jnp.ndarray, xx_up: jnp.ndarray,
                          xr: jnp.ndarray, xs: jnp.ndarray,
                          xxs: jnp.ndarray, theta: jnp.ndarray,
                          ainv2: jnp.ndarray, *, blk_n: int = 128,
-                         interpret: bool = True):
+                         interpret: bool):
     """w (Bu, N) choice mask; xx_up (Bu, P2) flattened outer products;
     xr (Bu, Dp) reward-weighted contexts; xs (Bs, Dp) scoring contexts;
     xxs (Bs, P2) their outer products; theta (N, Dp); ainv2 (N, P2) —
@@ -108,7 +106,7 @@ def bandit_update_pallas(w: jnp.ndarray, xx_up: jnp.ndarray,
             jax.ShapeDtypeStruct((N, Dp), jnp.float32),
             jax.ShapeDtypeStruct((Bs, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w, xx_up, xr, xs, xxs, theta, ainv2)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = float("-inf")
 
 
@@ -87,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
 def flash_attention_pallas(q, k, v, kv_valid=None, *, causal: bool = True,
                            window: int = 0,
                            softcap: float = 0.0, blk_q: int = 128,
-                           blk_k: int = 128, interpret: bool = True):
+                           blk_k: int = 128, interpret: bool):
     """q (B, Hq, Lq, hd); k, v (B, Hkv, Lk, hd); Hq % Hkv == 0.
 
     kv_valid: optional (B,) int32 — per-sequence number of valid cache
@@ -135,7 +133,7 @@ def flash_attention_pallas(q, k, v, kv_valid=None, *, causal: bool = True,
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

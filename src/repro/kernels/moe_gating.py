@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = float("-inf")
 
 
@@ -56,7 +54,7 @@ def _gate_kernel(logits_ref, vals_ref, idx_ref, psum_ref, asum_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("k", "blk_t", "interpret"))
 def moe_gating_pallas(logits: jnp.ndarray, k: int, *, blk_t: int = 256,
-                      interpret: bool = True):
+                      interpret: bool):
     """logits (T, E). Returns (vals (T, k) f32, idx (T, k) i32, aux f32)."""
     T, E = logits.shape
     blk_t = min(blk_t, max(T, 8))
@@ -80,7 +78,7 @@ def moe_gating_pallas(logits: jnp.ndarray, k: int, *, blk_t: int = 256,
             jax.ShapeDtypeStruct((nblk, E), jnp.float32),
             jax.ShapeDtypeStruct((nblk, E), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lp)

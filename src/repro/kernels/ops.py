@@ -22,7 +22,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gating import moe_gating_pallas
 from repro.kernels.route_step import (route_step_ivf_jit, route_step_jit,
                                       route_step_sharded_jit)
-from repro.kernels.router_topk import (router_topk_pallas,
+from repro.kernels.router_topk import (Q8_BLK_Q, router_topk_pallas,
                                        router_topk_q8_pallas)
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
@@ -30,6 +30,9 @@ LANE = 128
 
 
 def default_interpret() -> bool:
+    """Interpret mode for the CPU test path (no TPU backend); on a TPU
+    the wrappers compile the kernels.  The raw ``*_pallas`` entries
+    take no default — their callers state the mode."""
     return jax.default_backend() != "tpu"
 
 
@@ -164,38 +167,12 @@ def _dummies():
     return _DUMMIES
 
 
-_WARNED_NO_CACHE_SIZE = False
-
-
 def _count_compiles(jit_fn, call):
-    """Run ``call()`` and return (result, new jit-cache entries).
-
-    Compile detection reads the jit function's private ``_cache_size``
-    — on a JAX build without it, warn ONCE that the recompile counters
-    (and every zero-recompile guard built on them) are blind, instead
-    of letting them read as a vacuous flat 0.
-    """
-    global _WARNED_NO_CACHE_SIZE
-    try:
-        before = jit_fn._cache_size()
-    except AttributeError:              # pragma: no cover - older jax
-        before = None
-        if not _WARNED_NO_CACHE_SIZE:
-            _WARNED_NO_CACHE_SIZE = True
-            import warnings
-            warnings.warn(
-                "jit._cache_size() unavailable on this JAX version — "
-                "route_step compile counters (and zero-recompile "
-                "guards) cannot observe recompilation",
-                RuntimeWarning, stacklevel=2)
+    """Run ``call()`` and return (result, new jit-cache entries), read
+    from the jit function's ``_cache_size``."""
+    before = jit_fn._cache_size()
     out = call()
-    delta = 0
-    if before is not None:
-        try:
-            delta = max(0, jit_fn._cache_size() - before)
-        except AttributeError:          # pragma: no cover
-            pass
-    return out, delta
+    return out, max(0, jit_fn._cache_size() - before)
 
 
 # the padded catalog constants are identical across every batch routed
@@ -426,6 +403,8 @@ def router_topk(emb, queries, k: int,
     Q = queries.shape[0]
     interp = default_interpret() if interpret is None else interpret
     blk_n = _clamp_blk_n(blk_n, N)
+    if quant:
+        blk_q = Q8_BLK_Q                # the int8 tile's query rows
 
     # fold weights + row norms into the catalog; unit-normalize queries
     en = jnp.linalg.norm(emb, axis=1, keepdims=True) + 1e-9

@@ -28,8 +28,8 @@ into a single program (one device dispatch per routed batch):
      as arrays.
 
 On TPU (``use_pallas``) the kNN stage runs the Pallas ``router_topk``
-kernel (blocked MXU matmul + the shared ``block_topk``/``merge_topk``
-carry update) and the fallback re-score is its own ``top_k`` — the
+kernel (blocked MXU matmul + the in-kernel ``carry_block_topk`` carry
+update) and the fallback re-score is its own ``top_k`` — the
 structure XLA:TPU prefers; the single-matrix form above is the
 XLA:CPU-friendly lowering the test suite exercises.
 
@@ -77,15 +77,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ref import quantize_rows
-from repro.kernels.router_topk import (router_topk_pallas,
+from repro.kernels.router_topk import (Q8_BLK_Q, router_topk_pallas,
                                        router_topk_q8_pallas,
                                        tree_merge_topk)
 
 NEG_INF = float("-inf")
+
+
+def _f32_matmuls(fn):
+    """Trace ``fn`` with fp32 dots at full fp32 precision.  On the TPU
+    an f32 dot defaults to one bf16 pass, which moves cosine and blend
+    scores by ~1e-3 and reorders near-ties against the fp32 kernel and
+    the staged reference; the routing matmuls are tiny next to the
+    catalog stream, so exact fp32 costs little.  Integer (int8) dots
+    are exact either way."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+    return traced
 
 
 def _hier_topk(z, kk: int, chunk: int = 32):
@@ -137,16 +150,22 @@ def _knn_pallas_q8(q8, qs, e8, es, m1, k, blk_q, blk_n, interpret):
 
     Zero-padding the int8 feature axis is exact (zero columns add
     nothing to the int32 dot), so the scales pass through unchanged.
+    Query buckets below ``blk_q`` (the int8 tile's 32 rows) pad with
+    all-masked rows, which surface as -inf and are sliced off.
     """
     Q, D = q8.shape
     N = e8.shape[0]
     dpad = (-D) % 128
-    q8p = jnp.pad(q8, ((0, 0), (0, dpad)))
+    qpad = (-Q) % blk_q
+    q8p = jnp.pad(q8, ((0, qpad), (0, dpad)))
     e8p = jnp.pad(e8, ((0, 0), (0, dpad)))
+    qsp = jnp.pad(qs, ((0, qpad), (0, 0)))
+    m1p = jnp.pad(m1.astype(jnp.float32), ((0, qpad), (0, 0)))
     bias = jnp.zeros((1, N), jnp.float32)
-    return router_topk_q8_pallas(q8p, e8p, qs, es[None, :], m1.astype(
-        jnp.float32), bias, k, blk_q=blk_q, blk_n=blk_n,
-        interpret=interpret)
+    vals, idx = router_topk_q8_pallas(q8p, e8p, qsp, es[None, :], m1p,
+                                      bias, k, blk_q=blk_q, blk_n=blk_n,
+                                      interpret=interpret)
+    return vals[:Q], idx[:Q]
 
 
 # ----------------------------------------------------------------------
@@ -225,6 +244,7 @@ def _quant_operands(e2, e2s, M: int):
 # dense single-device program
 # ----------------------------------------------------------------------
 
+@_f32_matmuls
 def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
                      theta, ainv_flat, lpen, params, *, k: int, r: int,
                      n_tt: int, n_dm: int, has_fb: bool,
@@ -294,8 +314,8 @@ def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
         # the fallback re-score (primary rows masked out of it)
         m1 = bar(masks_table[ci])
         if quant:
-            vals, idx = _knn_pallas_q8(q8, qs, e8n, esn, m1, k, blk_q,
-                                       blk_n, interpret)
+            vals, idx = _knn_pallas_q8(q8, qs, e8n, esn, m1, k,
+                                       Q8_BLK_Q, blk_n, interpret)
         else:
             vals, idx = _knn_pallas(qn, embn, m1, k, blk_q, blk_n,
                                     interpret)
@@ -418,6 +438,7 @@ route_step_jit = jax.jit(
     jax.jit,
     static_argnames=("k", "r", "n_tt", "n_dm", "nprobe", "cap",
                      "has_fb", "has_ad", "has_load", "quant"))
+@_f32_matmuls
 def route_step_ivf_jit(e2, e2s, masks_table, counts_table, orig, cent,
                        T, W, ti, di, fb, theta, ainv_flat, lpen,
                        params, *, k: int, r: int, n_tt: int, n_dm: int,
@@ -581,6 +602,7 @@ def route_step_ivf_jit(e2, e2s, masks_table, counts_table, orig, cent,
     jax.jit,
     static_argnames=("mesh", "axis", "k", "r", "n_tt", "n_dm",
                      "has_fb", "has_ad", "has_load", "quant"))
+@_f32_matmuls
 def route_step_sharded_jit(e2, e2s, masks_table, counts_table, T, W,
                            ti, di, fb, theta, ainv_flat, lpen, params,
                            *, mesh, axis: str, k: int, r: int,
@@ -679,7 +701,7 @@ def route_step_sharded_jit(e2, e2s, masks_table, counts_table, T, W,
         mv, (mi, mc, ms) = tree_merge_topk(g[0], (g[1], g[2], g[3]))
         return mv, mi, mc, ms
 
-    vals, idx, csc, cos = shard_map(
+    vals, idx, csc, cos = jax.shard_map(
         _shard, mesh=mesh,
         in_specs=(P(axis, None),
                   P(axis, None) if quant else P(None, None),
@@ -690,7 +712,7 @@ def route_step_sharded_jit(e2, e2s, masks_table, counts_table, T, W,
                   P(axis) if has_load else P(None),
                   P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(e2, e2s, masks_table, fb, theta, ainv_flat, lpen,
       T, qn, W, zi, has_primary, params)
 

@@ -8,15 +8,13 @@ in VMEM scratch across catalog blocks:
   grid = (Q/BLK_Q, N/BLK_N), catalog axis innermost (sequential)
   per step:  scores = q_blk @ emb_blk^T            (MXU, 128-aligned)
              scores = where(mask_blk, scores, -inf) (VPU)
-             block top-k, then a sorted pairwise merge with the
-             running (vals, idx) carry
+             k rounds of row max / first-lane select merge the block
+             into the running (vals, idx) carry (``carry_block_topk``)
 
-The carry update is a per-block ``jax.lax.top_k`` followed by a
-bitonic merge of two sorted (Q, k) carries — O(k log k) per grid step
-on top of the block top-k, replacing the earlier k-pass argmax +
-one-hot scatter over a concatenated (Q, k + BLK_N) buffer
-(O(k * (k + BLK_N)) per step).  ``merge_topk``/``block_topk`` are
-shared with the fused ``route_step`` kernel.
+The carry update uses only row reductions, iota compares and selects,
+which Mosaic lowers; an in-kernel ``lax.top_k`` or gather by computed
+index does not compile for the chip.  ``merge_topk`` (bitonic, XLA
+side) reduces per-shard carries in the sharded ``route_step``.
 
 Dense blocked scan beats ANN graph traversal on TPU because pointer
 chasing is hostile to the systolic pipeline while a 100k x 128 catalog
@@ -35,9 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = float("-inf")
+# query rows per int8 kernel tile: the TPU's minimum int8 tile is
+# (32, 128), so compiled int8 runs stream 32-row query blocks
+Q8_BLK_Q = 32
 
 
 def _pow2_ge(x: int) -> int:
@@ -45,21 +44,46 @@ def _pow2_ge(x: int) -> int:
     return 1 << max(x - 1, 1).bit_length() if x > 1 else 1
 
 
-def block_topk(scores, col_idx, k: int):
-    """Top-k of one (Q, M) score block, descending, padded out to k.
+def carry_block_topk(cv, ci, scores, col0, k: int):
+    """Top-k of the union of the running carry and one scored block.
 
-    ``col_idx`` (Q, M) carries the global catalog column of each score.
-    When the block is narrower than k (k > BLK_N) the tail pads with
-    (-inf, -1).  Returns (vals (Q, k), idx (Q, k)) sorted descending.
+    cv/ci (Q, k) — the carry, sorted descending with ties in ascending
+    column order; scores (Q, M) — one catalog block whose lane j is
+    global column ``col0 + j`` (every carry column is lower).  k rounds
+    of: row max of each side, first lane holding it (iota + min), take
+    the carry's on a tie (it holds the lower columns), then knock the
+    taken lane out to -inf.  Only row reductions, iota compares and
+    selects — the forms Mosaic lowers — so it runs inside the Pallas
+    kernel on the chip; XLA's ``top_k``/gather do not lower there.
+    Lanes whose value is -inf (masked, padded, sub-threshold, or the
+    tail when fewer than k columns survive) carry index -1.
+    Returns the new carry (vals (Q, k) f32, idx (Q, k) i32).
     """
-    m = scores.shape[1]
-    kk = min(k, m)
-    v, p = jax.lax.top_k(scores, kk)
-    i = jnp.take_along_axis(col_idx, p, axis=1)
-    if kk < k:
-        v = jnp.pad(v, ((0, 0), (0, k - kk)), constant_values=NEG_INF)
-        i = jnp.pad(i, ((0, 0), (0, k - kk)), constant_values=-1)
-    return v, i
+    Q, M = scores.shape
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (Q, k), 1)
+    lane_m = jax.lax.broadcasted_iota(jnp.int32, (Q, M), 1)
+
+    def one_round(j, st):
+        cv, bv, ov, oi = st
+        mc = jnp.max(cv, axis=1, keepdims=True)
+        mb = jnp.max(bv, axis=1, keepdims=True)
+        take_c = mc >= mb
+        pc = jnp.min(jnp.where(cv == mc, lane_k, k), axis=1, keepdims=True)
+        pb = jnp.min(jnp.where(bv == mb, lane_m, M), axis=1, keepdims=True)
+        ic = jnp.max(jnp.where(lane_k == pc, ci, -1), axis=1,
+                     keepdims=True)
+        m = jnp.maximum(mc, mb)
+        i = jnp.where(m > NEG_INF, jnp.where(take_c, ic, col0 + pb), -1)
+        cv = jnp.where(take_c & (lane_k == pc), NEG_INF, cv)
+        bv = jnp.where(~take_c & (lane_m == pb), NEG_INF, bv)
+        ov = jnp.where(lane_k == j, m, ov)
+        oi = jnp.where(lane_k == j, i, oi)
+        return cv, bv, ov, oi
+
+    init = (cv, scores, jnp.full((Q, k), NEG_INF, jnp.float32),
+            jnp.full((Q, k), -1, jnp.int32))
+    _, _, ov, oi = jax.lax.fori_loop(0, k, one_round, init)
+    return ov, oi
 
 
 def _pad_const(p):
@@ -68,13 +92,14 @@ def _pad_const(p):
     return -1 if jnp.issubdtype(p.dtype, jnp.integer) else 0
 
 
-def merge_topk_multi(av, bv, a_payloads, b_payloads):
+def merge_topk(av, bv, a_payloads, b_payloads):
     """Top-k union of two sorted-descending (Q, k) carries, with any
     number of payload columns riding along every compare-exchange.
 
-    The values follow the same bitonic structure as ``merge_topk``
-    (one reversal exchange keeps the k largest of the 2k, then
-    log2(k) merge stages sort descending); each payload in
+    One bitonic compare-exchange of ``a`` against ``b`` reversed keeps
+    the k largest of the 2k, then log2(k) merge stages sort them
+    descending — O(k log k); inputs need not be power-of-two wide
+    (padded internally).  Each payload in
     ``a_payloads``/``b_payloads`` (tuples of (Q, k) arrays — indices,
     per-lane blend scores, cosines, ...) takes the exact same keep
     mask as the values, so lanes never mix payloads.  Ties keep the
@@ -113,24 +138,6 @@ def merge_topk_multi(av, bv, a_payloads, b_payloads):
     return v[:, :k], tuple(p[:, :k] for p in pl)
 
 
-def merge_topk(av, ai, bv, bi):
-    """Top-k of the union of two sorted-descending (Q, k) carries.
-
-    One bitonic compare-exchange of ``a`` against ``b`` reversed keeps
-    the k largest of the 2k (a bitonic sequence), then log2(k) merge
-    stages sort it descending — O(k log k) total, vs O(k^2 + k*BLK_N)
-    for re-running a k-pass argmax over the concatenation.  Indices
-    ride along through every exchange; ties keep the ``a`` (carry)
-    element, and within the sort both sides of an equal pair keep
-    their own payload, so no element is ever duplicated or dropped.
-    Inputs need not be power-of-two wide (padded internally).
-    One-payload wrapper over ``merge_topk_multi`` (shared with the
-    cross-shard tree reduction in ``route_step``).
-    """
-    v, (i,) = merge_topk_multi(av, bv, (ai,), (bi,))
-    return v, i
-
-
 def tree_merge_topk(vals, payloads):
     """Pairwise-tree reduction of S sorted-descending per-shard
     carries into ONE global (Q, k) top-k — the cross-shard step of the
@@ -139,7 +146,7 @@ def tree_merge_topk(vals, payloads):
     vals (S, Q, k) stacked per-shard top-k values (shard-major, e.g.
     from ``lax.all_gather``); payloads: tuple of (S, Q, k) arrays.
     Merges adjacent pairs per level (log2(S) levels of
-    ``merge_topk_multi``), always folding the HIGHER shard into the
+    ``merge_topk``), always folding the HIGHER shard into the
     lower so ties resolve toward the lowest shard — the same winner a
     single-device ``top_k`` over the concatenated catalog picks.
     Returns (vals (Q, k), tuple of payloads (Q, k)).
@@ -150,7 +157,7 @@ def tree_merge_topk(vals, payloads):
         nxt = []
         for i in range(0, len(parts) - 1, 2):
             (av, apl), (bv, bpl) = parts[i], parts[i + 1]
-            nxt.append(merge_topk_multi(av, bv, apl, bpl))
+            nxt.append(merge_topk(av, bv, apl, bpl))
         if len(parts) % 2:
             nxt.append(parts[-1])
         parts = nxt
@@ -174,6 +181,7 @@ def _router_topk_kernel(q_ref, emb_ref, mask_ref, bias_ref, vals_ref,
     bias = bias_ref[...]                                    # (1, BLK_N)
     scores = jax.lax.dot_general(
         q, emb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                 # (BLK_Q, BLK_N)
     # bias joins valid rows only: a heavy load penalty must stay
     # distinguishable from a failed hierarchical filter (-inf)
@@ -184,11 +192,8 @@ def _router_topk_kernel(q_ref, emb_ref, mask_ref, bias_ref, vals_ref,
         # never see a "best" match that is not a usable one
         scores = jnp.where(scores >= min_score, scores, NEG_INF)
 
-    col0 = jn * blk_n
-    col_idx = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    bv, bi = block_topk(scores, col_idx, k)
-    new_v, new_i = merge_topk(sv_ref[...], si_ref[...], bv, bi)
+    new_v, new_i = carry_block_topk(sv_ref[...], si_ref[...], scores,
+                                    jn * blk_n, k)
     sv_ref[...] = new_v
     si_ref[...] = new_i
 
@@ -203,7 +208,7 @@ def _router_topk_kernel(q_ref, emb_ref, mask_ref, bias_ref, vals_ref,
 def router_topk_pallas(qn: jnp.ndarray, embn: jnp.ndarray, mask: jnp.ndarray,
                        bias: jnp.ndarray, k: int, *, blk_q: int = 8,
                        blk_n: int = 512, min_score: float = NEG_INF,
-                       interpret: bool = True):
+                       interpret: bool):
     """qn (Q, D) unit rows; embn (N, D) unit(+weighted) rows;
     mask (Q, N) f32 — per-query hierarchical filter mask (ops.py
     broadcasts a shared (N,) mask to all queries); bias (1, N) f32 —
@@ -245,7 +250,7 @@ def router_topk_pallas(qn: jnp.ndarray, embn: jnp.ndarray, mask: jnp.ndarray,
             pltpu.VMEM((blk_q, k), jnp.float32),
             pltpu.VMEM((blk_q, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qn, embn, mask, bias)
@@ -274,19 +279,19 @@ def _router_topk_q8_kernel(q_ref, emb_ref, qs_ref, es_ref, mask_ref,
     # of the catalog block ever materializes; the only fp32 work per
     # (BLK_Q, BLK_N) tile is ONE elementwise rescale by the per-row
     # scale outer product, right at the top-k boundary
+    # (explicit DEFAULT: an ambient fp32 matmul precision must not
+    # reach the integer dot, which Mosaic then refuses)
     acc = jax.lax.dot_general(
         q8, e8, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32)                   # (BLK_Q, BLK_N)
     scores = acc.astype(jnp.float32) * (qs_ref[...] * es_ref[...])
     scores = jnp.where(mask_ref[...] > 0, scores + bias_ref[...], NEG_INF)
     if min_score != NEG_INF:
         scores = jnp.where(scores >= min_score, scores, NEG_INF)
 
-    col0 = jn * blk_n
-    col_idx = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    bv, bi = block_topk(scores, col_idx, k)
-    new_v, new_i = merge_topk(sv_ref[...], si_ref[...], bv, bi)
+    new_v, new_i = carry_block_topk(sv_ref[...], si_ref[...], scores,
+                                    jn * blk_n, k)
     sv_ref[...] = new_v
     si_ref[...] = new_i
 
@@ -301,9 +306,9 @@ def _router_topk_q8_kernel(q_ref, emb_ref, qs_ref, es_ref, mask_ref,
 def router_topk_q8_pallas(q8: jnp.ndarray, e8: jnp.ndarray,
                           qscale: jnp.ndarray, escale: jnp.ndarray,
                           mask: jnp.ndarray, bias: jnp.ndarray, k: int,
-                          *, blk_q: int = 8, blk_n: int = 512,
+                          *, blk_q: int = Q8_BLK_Q, blk_n: int = 512,
                           min_score: float = NEG_INF,
-                          interpret: bool = True):
+                          interpret: bool):
     """int8-quantized ``router_topk_pallas``.
 
     q8 (Q, D) / e8 (N, D) int8 rows quantized symmetrically per row;
@@ -315,9 +320,8 @@ def router_topk_q8_pallas(q8: jnp.ndarray, e8: jnp.ndarray,
     scan that is the speedup (see benchmarks/roofline.py) — and the
     fp32 rescale happens once per tile at the top-k boundary.
 
-    NOTE on tiling: the TPU int8 minimum tile is (32, 128); compiled
-    (non-interpret) runs should use blk_q % 32 == 0.  The interpret
-    path (CPU CI) accepts the fp32 default blk_q=8.
+    Tiling: the TPU int8 minimum tile is (32, 128), so callers pass
+    ``blk_q=Q8_BLK_Q`` (``ops`` pads the query axis to it).
 
     Same shape contract and returns as ``router_topk_pallas``.
     """
@@ -356,7 +360,7 @@ def router_topk_q8_pallas(q8: jnp.ndarray, e8: jnp.ndarray,
             pltpu.VMEM((blk_q, k), jnp.float32),
             pltpu.VMEM((blk_q, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q8, e8, qscale, escale, mask, bias)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
                 y_ref, hf_ref, state_ref, *, chunk: int):
@@ -73,7 +71,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(x, dt, A, B, C, h0=None, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """SSD scan. Shapes per kernels/ref.py::ssd_scan.
 
     x (Bb, L, H, P); dt (Bb, L, H); A (H,); B/C (Bb, L, N);
@@ -114,7 +112,7 @@ def ssd_scan_pallas(x, dt, A, B, C, h0=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((Bb, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, dtp, A, Bp, Cp, h0)

@@ -8,17 +8,26 @@ never touches jax device state.  TPU v5e target:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules and
+    ``with_sharding_constraint`` calls are hints to the partitioner
+    (``make_mesh`` defaults to ``Explicit`` axes, under which those
+    constraints become type assertions)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke/serving runs."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_routing_mesh(n_devices: int | None = None):
@@ -31,4 +40,4 @@ def make_routing_mesh(n_devices: int | None = None):
     from repro.sharding.rules import CATALOG_AXIS
     nd = jax.device_count() if n_devices is None else int(n_devices)
     assert 1 <= nd <= jax.device_count(), (nd, jax.device_count())
-    return jax.make_mesh((nd,), (CATALOG_AXIS,))
+    return _auto_mesh((nd,), (CATALOG_AXIS,))

@@ -26,6 +26,7 @@ from repro.core.analyzer import AnalyzerConfig, TaskAnalyzer
 from repro.core.orchestrator import OptiRoute
 from repro.core.preferences import PROFILES
 from repro.data.workload import make_workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.catalog import build_catalog
 from repro.serving.engine import Request, ServingEngine
 
@@ -175,6 +176,7 @@ def main(argv=None):
     ap.add_argument("--max-wait-ms", type=float, default=10.0,
                     help="aggregation window age bound (--async / --soak)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     obs_on = (args.metrics_out or args.trace_out
               or args.metrics_port is not None)

@@ -38,11 +38,7 @@ def _act_constraint(x, *, vocab_axis: bool = False):
     §Perf, gemma2 hillclimb).  No-op outside a mesh context (plain
     jit in unit tests) and on non-divisible axes.
     """
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is not None:
-        mesh = get_mesh()
-    else:   # jax < 0.5: the context mesh lives in thread resources
-        mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or "data" not in mesh.axis_names:
         return x
     da = ("pod", "data") if "pod" in mesh.axis_names else ("data",)

@@ -96,7 +96,8 @@ class ModelRunner:
         batch = self._batch(tokens)
         last, cache, pos = M.prefill(self.params, cfg, batch,
                                      max_len=Lp + max_new + 8)
-        tok = jnp.argmax(last, axis=-1).astype(jnp.int32)[:, None]
+        tok = jnp.argmax(last[:, :cfg.vocab_size],
+                         axis=-1).astype(jnp.int32)[:, None]
         out = [np.asarray(tok)]
         for _ in range(max_new - 1):
             logits, tok, cache = self._decode(

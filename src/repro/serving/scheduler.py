@@ -129,7 +129,7 @@ class ContinuousBatcher:
                 return slot_cache.at[:, i].set(one[:, 0])
             self.cache = jax.tree_util.tree_map(insert, self.cache, cache1)
             self.pos[i] = int(pos1[0])
-            self._next_tok[i] = int(jnp.argmax(last[0]))
+            self._next_tok[i] = int(jnp.argmax(last[0, :self.cfg.vocab_size]))
             req.slot = i
             req.started_s = time.perf_counter()
             if self.load is not None:

@@ -39,7 +39,10 @@ def make_decode_step(cfg: ModelConfig, *, long_mode: bool = False):
     def serve_step(params, cache, batch):
         logits, cache = M.decode_step(params, cfg, cache, batch,
                                       long_mode=long_mode)
-        # greedy next token (serving engines may sample outside the jit)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        # greedy next token (serving engines may sample outside the
+        # jit) over the real vocabulary: the padded tail of the
+        # embedding table is not a token
+        next_tok = jnp.argmax(logits[:, :cfg.vocab_size],
+                              axis=-1).astype(jnp.int32)[:, None]
         return logits, next_tok, cache
     return serve_step

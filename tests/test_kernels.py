@@ -135,6 +135,27 @@ def test_router_topk_row_bias_matches_ref():
     assert mask[np.asarray(i1)[fin]].all()
 
 
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("k", [4, 200])
+def test_router_topk_ties_resolve_to_lowest_column(k, quant):
+    """Exact ties across catalog blocks come back in ascending column
+    order (``lax.top_k``'s contract on the whole catalog), k wider
+    than a 128-lane block included, and -inf lanes carry index -1."""
+    N, D, Q = 384, 8, 3
+    base = RNG.random((6, D)).astype(np.float32)
+    emb = base[np.arange(N) % 6]                 # 64 copies of 6 rows
+    q = RNG.random((Q, D)).astype(np.float32)
+    mask = np.arange(N) < N - 32                 # a masked tail
+    v1, i1 = K.router_topk(emb, q, k, mask=mask, blk_n=128, quant=quant)
+    v2, i2 = R.router_topk(jnp.asarray(emb), jnp.asarray(q), k,
+                           mask=jnp.asarray(mask), quant=quant)
+    v1, i1, i2 = np.asarray(v1), np.asarray(i1), np.asarray(i2)
+    fin = np.isfinite(v1)
+    np.testing.assert_array_equal(fin, np.isfinite(np.asarray(v2)))
+    np.testing.assert_array_equal(i1[fin], i2[fin])
+    assert (i1[~fin] == -1).all()
+
+
 @pytest.mark.parametrize("Bu,Bs,N,D", [
     (1, 1, 1, 3),       # every axis at its minimum
     (7, 5, 130, 9),     # N just past one 128 block

@@ -20,7 +20,7 @@ RNG = np.random.default_rng(7)
 
 
 # ----------------------------------------------------------------------
-# the rewritten top-k merge (shared by router_topk and route_step)
+# the bitonic top-k merge (the sharded route_step's cross-shard step)
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("k", [1, 2, 3, 5, 8, 13])
@@ -33,8 +33,8 @@ def test_merge_topk_matches_full_sort(k):
         b = -np.sort(-rng.integers(0, 6, (4, k)).astype(np.float32))
         ai = rng.integers(0, 100, (4, k)).astype(np.int32)
         bi = rng.integers(100, 200, (4, k)).astype(np.int32)
-        v, i = merge_topk(jnp.asarray(a), jnp.asarray(ai),
-                          jnp.asarray(b), jnp.asarray(bi))
+        v, (i,) = merge_topk(jnp.asarray(a), jnp.asarray(b),
+                             (jnp.asarray(ai),), (jnp.asarray(bi),))
         want = -np.sort(-np.concatenate([a, b], axis=1), axis=1)[:, :k]
         np.testing.assert_array_equal(np.asarray(v), want)
         # every returned index carries its own value (no element was
@@ -53,8 +53,8 @@ def test_merge_topk_with_neginf_padding():
     b = np.array([[2.0, -np.inf, -np.inf]], np.float32)
     ai = np.array([[0, 1, -1]], np.int32)
     bi = np.array([[9, -1, -1]], np.int32)
-    v, i = merge_topk(jnp.asarray(a), jnp.asarray(ai),
-                      jnp.asarray(b), jnp.asarray(bi))
+    v, (i,) = merge_topk(jnp.asarray(a), jnp.asarray(b),
+                         (jnp.asarray(ai),), (jnp.asarray(bi),))
     np.testing.assert_array_equal(np.asarray(v)[0], [3.0, 2.0, 1.0])
     np.testing.assert_array_equal(np.asarray(i)[0], [0, 9, 1])
 

@@ -61,14 +61,7 @@ def test_pjit_train_step_on_host_mesh():
     cfg = get_smoke("llama3.2-1b")
     mesh = make_host_mesh()
     rng = np.random.default_rng(0)
-    set_mesh = getattr(jax, "set_mesh", None)
-    # jax < 0.5 has no jax.set_mesh and jit only accepts Shardings (not
-    # bare PartitionSpecs): wrap specs in NamedSharding there.
-    wrap = ((lambda t: t) if set_mesh is not None else
-            (lambda t: jax.tree_util.tree_map(
-                lambda s: jax.sharding.NamedSharding(mesh, s), t,
-                is_leaf=lambda x: isinstance(x, P))))
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         pspecs = R.param_specs(cfg, mesh, params)
         opt = init_opt_state(params)
@@ -77,8 +70,7 @@ def test_pjit_train_step_on_host_mesh():
                  "labels": jnp.asarray(rng.integers(0, 64, (2, 16)), jnp.int32)}
         bspecs = R.batch_spec(cfg, mesh, batch)
         step = jax.jit(make_train_step(cfg),
-                       in_shardings=(wrap(pspecs), wrap(ospecs),
-                                     wrap(bspecs)))
+                       in_shardings=(pspecs, ospecs, bspecs))
         params2, opt2, metrics = step(params, opt, batch)
         assert np.isfinite(float(metrics["loss"]))
 
