@@ -43,7 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.preferences import N_METRICS, TaskSignature, resolve
-from repro.obs.trace import NOOP_SPAN
+from repro.obs.trace import span
 from repro.analysis.sanitize import make_lock
 
 # cache_funnel outcome kinds (Telemetry.cache_funnel key set, stable
@@ -264,17 +264,15 @@ class SemanticCache:
         Like ``lookup`` but hit rows are materialized under the SAME
         lock, so a concurrent put/eviction/expiry between lookup and
         get can never invalidate a hit mid-serve."""
-        span = self.tracer.span("cache_lookup",
-                                batch=int(np.asarray(vecs).shape[0])) \
-            if self.tracer is not None else NOOP_SPAN
-        with span:
+        with span(self.tracer, "cache_lookup",
+                  batch=int(np.asarray(vecs).shape[0])) as sp:
             with self._lock:
                 hit, slot, sim = self._lookup_locked(
                     np.asarray(vecs, np.float32),
                     np.asarray(fps, np.int64))
                 entries = [self._entry_locked(int(s)) if h else None
                            for h, s in zip(hit, slot)]
-            span.set(hits=int(np.asarray(hit).sum()))
+            sp.set(hits=int(np.asarray(hit).sum()))
         return hit, entries, sim
 
     def _entry_locked(self, slot: int) -> CacheEntry:

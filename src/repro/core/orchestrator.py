@@ -28,7 +28,7 @@ from repro.core.mres import MRES, ModelEntry
 from repro.core.preferences import (TaskSignature, UserPreferences,
                                     resolve_batch)
 from repro.core.routing import RoutingDecision, RoutingEngine
-from repro.obs.trace import NOOP_SPAN
+from repro.obs.trace import span
 
 
 class RoutedQuery:
@@ -210,6 +210,17 @@ class OptiRoute:
         if len(prefs_list) != B:
             raise ValueError(f"prefs batch size {len(prefs_list)} != "
                              f"text batch size {B}")
+        with span(self.tracer, "route_all", batch=B):
+            out = self._route_all(texts, prefs_list)
+            for rq in out:
+                self._record(rq)
+            if self.adaptive is not None and self.reward_fn is not None:
+                self.observe(out)
+        return out
+
+    def _route_all(self, texts: Sequence[str],
+                   prefs_list: List[UserPreferences]) -> List[RoutedQuery]:
+        B = len(texts)
         tr = self.tracer
         if self._fully_fused_ok():
             # ONE device program from token ids to model choice: the
@@ -217,70 +228,50 @@ class OptiRoute:
             # encoder itself runs inside the fused dispatch, which
             # emits its own route_step span with path="fused")
             an = self.analyzer
-            t0 = time.time()
-            if tr is not None:
-                with tr.span("analyze", path="fused", batch=B):
-                    toks = an.encode_batch(list(texts))
-            else:
+            t0 = time.perf_counter()
+            with span(tr, "analyze", path="fused", batch=B):
                 toks = an.encode_batch(list(texts))
-            t1 = time.time()
+            t1 = time.perf_counter()
             batch = self.engine.route_tokens_batch(
                 an.params, an.cfg, toks, prefs_list)
-            t2 = time.time()
-            out = [RoutedQuery(text=t, batch=batch, batch_idx=i,
-                               analyzer_s=(t1 - t0) / B,
-                               route_s=(t2 - t1) / B)
-                   for i, t in enumerate(texts)]
-            for rq in out:
-                self._record(rq)
-            if self.adaptive is not None and self.reward_fn is not None:
-                self.observe(out)
-            return out
-        t0 = time.time()
-        if tr is not None:
-            with tr.span("analyze", batch=B):
-                sigs = self.analyzer.analyze_batch(list(texts))
-        else:
+            t2 = time.perf_counter()
+            return [RoutedQuery(text=t, batch=batch, batch_idx=i,
+                                analyzer_s=(t1 - t0) / B,
+                                route_s=(t2 - t1) / B)
+                    for i, t in enumerate(texts)]
+        t0 = time.perf_counter()
+        with span(tr, "analyze", batch=B):
             sigs = self.analyzer.analyze_batch(list(texts))
-        t1 = time.time()
+        t1 = time.perf_counter()
         if self.merger is None and self.engine._fused_ok():
             batch = self.engine.route_many_batch(prefs_list, sigs)
-            t2 = time.time()
-            out = [RoutedQuery(text=t, sig=s, batch=batch, batch_idx=i,
-                               analyzer_s=(t1 - t0) / B,
-                               route_s=(t2 - t1) / B)
-                   for i, (t, s) in enumerate(zip(texts, sigs))]
-        else:
-            if tr is not None:
-                with tr.span("route_step", path="staged", batch=B):
-                    decisions = self.engine.route_many(prefs_list, sigs)
-            else:
-                decisions = self.engine.route_many(prefs_list, sigs)
-            if self.merger is not None:
-                low = [i for i, d in enumerate(decisions)
-                       if d.score < self.merger.score_threshold]
-                grew = False
-                for i in low:
-                    if self.merger.maybe_merge(
-                            prefs_list[i], sigs[i],
-                            decisions[i].score) is not None:
-                        grew = True
-                if grew:               # re-route low scorers in one pass
-                    redo = self.engine.route_many(
-                        [prefs_list[i] for i in low],
-                        [sigs[i] for i in low])
-                    for j, i in enumerate(low):
-                        decisions[i] = redo[j]
-            t2 = time.time()
-            out = [RoutedQuery(text=t, sig=s, decision=d,
-                               analyzer_s=(t1 - t0) / B,
-                               route_s=(t2 - t1) / B)
-                   for t, s, d in zip(texts, sigs, decisions)]
-        for rq in out:
-            self._record(rq)
-        if self.adaptive is not None and self.reward_fn is not None:
-            self.observe(out)
-        return out
+            t2 = time.perf_counter()
+            return [RoutedQuery(text=t, sig=s, batch=batch, batch_idx=i,
+                                analyzer_s=(t1 - t0) / B,
+                                route_s=(t2 - t1) / B)
+                    for i, (t, s) in enumerate(zip(texts, sigs))]
+        with span(tr, "route_step", path="staged", batch=B):
+            decisions = self.engine.route_many(prefs_list, sigs)
+        if self.merger is not None:
+            low = [i for i, d in enumerate(decisions)
+                   if d.score < self.merger.score_threshold]
+            grew = False
+            for i in low:
+                if self.merger.maybe_merge(
+                        prefs_list[i], sigs[i],
+                        decisions[i].score) is not None:
+                    grew = True
+            if grew:                   # re-route low scorers in one pass
+                redo = self.engine.route_many(
+                    [prefs_list[i] for i in low],
+                    [sigs[i] for i in low])
+                for j, i in enumerate(low):
+                    decisions[i] = redo[j]
+        t2 = time.perf_counter()
+        return [RoutedQuery(text=t, sig=s, decision=d,
+                            analyzer_s=(t1 - t0) / B,
+                            route_s=(t2 - t1) / B)
+                for t, s, d in zip(texts, sigs, decisions)]
 
     # ----------------------- adaptive loop -----------------------
     def observe(self, rqs: Sequence[RoutedQuery],
@@ -329,11 +320,8 @@ class OptiRoute:
         todo = sorted(set(fresh) | set(cacheable))
         if not todo:
             return None
-        span = self.tracer.span("observe", batch=len(rqs),
-                                fresh=len(fresh),
-                                cacheable=len(cacheable)) \
-            if self.tracer is not None else NOOP_SPAN
-        with span:
+        with span(self.tracer, "observe", batch=len(rqs),
+                  fresh=len(fresh), cacheable=len(cacheable)):
             if qualities is None:
                 qual = {i: float(self.reward_fn(rqs[i])) for i in todo}
             else:
@@ -394,9 +382,9 @@ class OptiRoute:
         k = max(1, int(round(n * self.batch_sample_frac)))
         rng = np.random.default_rng(seed)
         pick = rng.choice(n, size=min(k, n), replace=False)
-        t0 = time.time()
+        t0 = time.perf_counter()
         sigs = self.analyzer.analyze_batch([texts[i] for i in pick])
-        t1 = time.time()
+        t1 = time.perf_counter()
         tt = Counter(s.task_type for s in sigs).most_common(1)[0][0]
         dm = Counter(s.domain for s in sigs).most_common(1)[0][0]
         agg = TaskSignature(
@@ -405,7 +393,8 @@ class OptiRoute:
             confidence=float(np.mean([s.confidence for s in sigs])))
         decision = self.engine.route(prefs, agg)
         stats = {"batch": n, "sampled": len(pick),
-                 "analyzer_s": t1 - t0, "route_s": time.time() - t1,
+                 "analyzer_s": t1 - t0,
+                 "route_s": time.perf_counter() - t1,
                  "aggregate_sig": agg}
         return decision, sigs, stats
 

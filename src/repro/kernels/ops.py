@@ -25,6 +25,7 @@ from repro.kernels.route_step import (route_step_ivf_jit, route_step_jit,
 from repro.kernels.router_topk import (Q8_BLK_Q, router_topk_pallas,
                                        router_topk_q8_pallas)
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.obs.trace import span
 
 LANE = 128
 
@@ -501,10 +502,11 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
     Dispatch/compile counts land in ``route_step_stats``; an attached
     ``telemetry`` additionally receives THIS call's (1 dispatch,
     compile delta) directly, so concurrent callers never read each
-    other's deltas out of the shared counters.  ``tracer`` (an
-    ``obs.trace.Tracer``) wraps the dispatch in a ``route_step`` span
-    carrying the selected path, shape bucket, quantization mode, shard
-    count and compile delta; an attached cost profiler (see
+    other's deltas out of the shared counters.  The dispatch runs in a
+    ``route_step`` span (``obs.trace.span``: in the profiler's timeline,
+    and in ``tracer``'s ring when one is given, carrying the selected
+    path, shape bucket, quantization mode, shard count and compile
+    delta); an attached cost profiler (see
     ``set_cost_profiler``) gets each NEW shape bucket's bound call to
     read ``compiled.cost_analysis()`` from.
 
@@ -635,14 +637,11 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
     prof = _COST_PROFILER
     if prof is not None:
         prof.capture((path, qp, n_pad, quant, shards), jit_fn, call)
-    if tracer is not None:
-        with tracer.span("route_step", path=path, batch=B,
-                         q_bucket=qp, n_bucket=n_pad, catalog_n=n,
-                         quant=quant, shards=shards) as sp:
-            out, compiles = _count_compiles(jit_fn, call)
-            sp.set(compiles=compiles)
-    else:
+    with span(tracer, "route_step", path=path, batch=B, q_bucket=qp,
+              n_bucket=n_pad, catalog_n=n, quant=quant,
+              shards=shards) as sp:
         out, compiles = _count_compiles(jit_fn, call)
+        sp.set(compiles=compiles)
     _bump("route_step", compiles)
     hook = _RECOMPILE_HOOK
     if hook is not None:
@@ -705,14 +704,10 @@ def analyze_step(params, cfg, tokens, *, telemetry=None,
     if prof is not None:
         prof.capture(("analyze", qp, L, quant, 1), analyze_step_jit,
                      call)
-    if tracer is not None:
-        with tracer.span("analyze_step", path="analyze", batch=B,
-                         q_bucket=qp, n_bucket=L, quant=quant,
-                         shards=1) as sp:
-            out, compiles = _count_compiles(analyze_step_jit, call)
-            sp.set(compiles=compiles)
-    else:
+    with span(tracer, "analyze_step", path="analyze", batch=B,
+              q_bucket=qp, n_bucket=L, quant=quant, shards=1) as sp:
         out, compiles = _count_compiles(analyze_step_jit, call)
+        sp.set(compiles=compiles)
     _bump("analyze_step", compiles)
     hook = _RECOMPILE_HOOK
     if hook is not None:
@@ -810,16 +805,11 @@ def analyze_route_step(params, cfg, tokens, emb, tt_matrix, dm_matrix,
     if prof is not None:
         prof.capture(("fused", qp, np_pad, (quant, aquant), 1),
                      analyze_route_step_jit, call)
-    if tracer is not None:
-        with tracer.span("route_step", path="fused", batch=B,
-                         q_bucket=qp, n_bucket=np_pad, catalog_n=n,
-                         quant=quant, analyzer_quant=aquant,
-                         shards=1) as sp:
-            out, compiles = _count_compiles(analyze_route_step_jit,
-                                            call)
-            sp.set(compiles=compiles)
-    else:
+    with span(tracer, "route_step", path="fused", batch=B, q_bucket=qp,
+              n_bucket=np_pad, catalog_n=n, quant=quant,
+              analyzer_quant=aquant, shards=1) as sp:
         out, compiles = _count_compiles(analyze_route_step_jit, call)
+        sp.set(compiles=compiles)
     _bump("route_step", compiles)
     _bump("analyze_step", compiles)
     hook = _RECOMPILE_HOOK

@@ -43,11 +43,12 @@ from dataclasses import dataclass
 from typing import (Any, AsyncIterator, Deque, Dict, List, Optional,
                     Sequence, Tuple)
 
+from repro.obs.trace import span
 from repro.serving.engine import Request, Response, ServingEngine
 from repro.analysis.sanitize import make_lock
 
 __all__ = ["TokenBucket", "TenantPolicy", "MicroBatcher",
-           "AsyncServingEngine", "DEFAULT_TENANT"]
+           "AsyncServingEngine", "DEFAULT_TENANT", "window_stats"]
 
 DEFAULT_TENANT = "default"
 
@@ -222,10 +223,16 @@ class MicroBatcher:
     def take(self, now: float, limit: Optional[int] = None) -> List[Any]:
         """Dequeue up to ``min(limit, max_batch)`` items by weighted
         deficit round-robin across backlogged tenants."""
+        return [item for _, item in self.take_stamped(now, limit)]
+
+    def take_stamped(self, now: float, limit: Optional[int] = None
+                     ) -> List[Tuple[float, Any]]:
+        """``take``, each item paired with the ``now`` it was offered
+        at."""
         del now  # dequeue is instantaneous; signature mirrors offer()
         budget = self.max_batch if limit is None \
             else min(int(limit), self.max_batch)
-        out: List[Any] = []
+        out: List[Tuple[float, Any]] = []
         with self._lock:
             active = [t for t in self._order if self._queues[t]]
             while len(out) < budget and active:
@@ -235,7 +242,7 @@ class MicroBatcher:
                     self._deficit[t] += self.policy(t).weight
                     while q and self._deficit[t] >= 1.0 \
                             and len(out) < budget:
-                        out.append(q.popleft()[1])
+                        out.append(q.popleft())
                         self._deficit[t] -= 1.0
                     if not q:
                         active.remove(t)
@@ -246,6 +253,17 @@ class MicroBatcher:
         return out
 
 
+def window_stats(offered: Sequence[float], start: float,
+                 backlog: int) -> Dict[str, float]:
+    """The ``window`` span's stats: the window's size, the sum and the
+    largest of its requests' queue waits in ms (from ``offer`` to
+    ``start``, the start of the window's service, on the batcher's
+    clock) and the requests still queued."""
+    waits = [(start - t) * 1e3 for t in offered]
+    return {"size": len(waits), "wait_ms_sum": sum(waits),
+            "wait_ms_max": max(waits, default=0.0), "backlog": backlog}
+
+
 class AsyncServingEngine:
     """Event-loop front end over a synchronous ``ServingEngine``.
 
@@ -254,6 +272,9 @@ class AsyncServingEngine:
     dequeue, and runs ``engine.submit(window)`` on ``executor`` (the
     loop's default thread pool when None) — so at most one route/
     generate pass is in flight and the event loop stays responsive.
+    Each window is served inside a ``window`` span (``obs.trace``)
+    whose stats carry its size, its requests' queue waits and the
+    backlog left behind it.
     Per-tenant backlog and intake counters are exported as telemetry
     gauges (``tenant_backlog{t}`` etc.) when the router carries a
     ``Telemetry``.
@@ -387,33 +408,42 @@ class AsyncServingEngine:
                 except asyncio.TimeoutError:
                     pass
                 continue
-            items = self.batcher.take(now)
+            items = self.batcher.take_stamped(now)
             if items:
                 await self._flush(items, loop)
 
-    async def _flush(self, items: Sequence[Tuple[Request, asyncio.Future]],
-                     loop) -> None:
-        reqs = [r for r, _ in items]
-        self.windows.append(len(reqs))
+    async def _flush(self, items: Sequence[Tuple[float, Tuple[
+            Request, asyncio.Future]]], loop) -> None:
+        self.windows.append(len(items))
         tel = self.router_telemetry()
         if tel is not None:
             for t, n in self.batcher.backlog().items():
                 tel.set_gauge(f"tenant_backlog_{t}", float(n))
-            tel.set_gauge("window_size", float(len(reqs)))
+            tel.set_gauge("window_size", float(len(items)))
         try:
             resps = await loop.run_in_executor(
-                self._executor, self.engine.submit, reqs)
+                self._executor, self._serve, items, loop.time)
         except Exception as e:                     # noqa: BLE001
             # submit itself should degrade per group; anything that
             # still escapes (e.g. routing failure) fails THIS window's
             # futures, never the flusher loop
-            for _, fut in items:
+            for _, (_, fut) in items:
                 if not fut.done():
                     fut.set_exception(e)
             return
-        for (_, fut), resp in zip(items, resps):
+        for (_, (_, fut)), resp in zip(items, resps):
             if not fut.done():
                 fut.set_result(resp)
+
+    def _serve(self, items, clock) -> List[Response]:
+        """One window on the executor thread: ``engine.submit`` inside
+        the ``window`` span, its queue waits read from ``clock`` (the
+        one ``offer`` was stamped with) as service starts."""
+        stats = window_stats([t for t, _ in items], clock(),
+                             self.batcher.pending())
+        with span(getattr(self.engine, "tracer", None), "window",
+                  stats=stats):
+            return self.engine.submit([r for _, (r, _) in items])
 
     # ---------------- streaming ----------------
     async def stream(self, request: Request) -> AsyncIterator[int]:

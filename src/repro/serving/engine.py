@@ -50,7 +50,7 @@ from repro.core.orchestrator import OptiRoute
 from repro.core.preferences import TaskSignature, resolve_batch
 from repro.core.telemetry import RouteEvent
 from repro.data.tokenizer import HashTokenizer
-from repro.obs.trace import NOOP_SPAN
+from repro.obs.trace import NOOP_SPAN, span
 from repro.serving.load import LoadTracker, plan_admission
 
 
@@ -73,6 +73,8 @@ class Response:
     sim_latency_s: float
     route_s: float
     analyzer_s: float
+    generate_s: float = 0.0           # measured wall time of the group's
+                                      # generate, amortized per request
     fallback: str = ""
     rq: Any = None                    # RoutedQuery (adaptive loop handle)
     admission: str = "admitted"       # admitted | rerouted | shed | failed
@@ -154,11 +156,8 @@ class ServingEngine:
         keys = fps = None
         miss = list(range(len(reqs)))
         tel = self.router.telemetry
-        tr = self.tracer
-        batch_span = tr.start_trace("submit", batch=len(reqs),
-                                    mode="interactive") \
-            if tr is not None else NOOP_SPAN
-        with batch_span:
+        with span(self.tracer, "submit", batch=len(reqs),
+                  mode="interactive") as batch_span:
             # featurize each request's preferences EXACTLY once: the
             # resolved UserPreferences instances (with their memoized
             # weight vectors) feed the cache key vectors, the
@@ -210,7 +209,9 @@ class ServingEngine:
         ``request`` root carrying ids and verdicts, with child spans
         for exactly the stages that ran for it (a cache hit gets only
         its ``cache_lookup``; a shed request stops at ``admission``).
-        Durations are the batch stage costs amortized per request.
+        Durations are measured wall times: the batch's analyze and
+        route costs and the request's group's generate, each amortized
+        per request.
         Each ``Response`` leaves with its ``trace_id``/``trace_root``
         stamped so later ``observe`` calls can attach to the tree."""
         tr = self.tracer
@@ -221,7 +222,7 @@ class ServingEngine:
             root = tr.record_span(
                 "request",
                 duration_s=resp.analyzer_s + resp.route_s
-                + resp.sim_latency_s,
+                + resp.generate_s,
                 request_id=r.id, tenant=r.tenant, batch=B,
                 batch_trace=batch_span.trace_id, model=resp.model,
                 admission=resp.admission, cache_hit=resp.cache_hit)
@@ -250,7 +251,7 @@ class ServingEngine:
                                outcome="failed", error=resp.error)
             elif not resp.shed:
                 tr.record_span("generate", parent=root,
-                               duration_s=resp.sim_latency_s,
+                               duration_s=resp.generate_s,
                                model=resp.model)
 
     def _route_and_serve(self, requests: Sequence[Request], prefs_res,
@@ -284,8 +285,8 @@ class ServingEngine:
         pending = np.zeros(self.load.n_models, np.int64) \
             if self.load is not None else None
         tr = self.tracer
-        adm_span = tr.span("admission", batch=len(routed)) \
-            if tr is not None and self.load is not None else NOOP_SPAN
+        adm_span = span(tr, "admission", batch=len(routed)) \
+            if self.load is not None else NOOP_SPAN
         with adm_span:
             for r, rq in routed:
                 if self.load is None:
@@ -316,11 +317,11 @@ class ServingEngine:
             if kind != "shed":
                 groups[(model, r.max_new)].append(i)
         out: List[Optional[Response]] = [None] * len(requests)
-        gen_span = tr.span("generate", groups=len(groups)) \
-            if tr is not None else NOOP_SPAN
-        with gen_span:
+        with span(tr, "generate", groups=len(groups)):
             for (model, max_new), idxs in groups.items():
-                entry = self.router.mres.entry(model)
+                with span(tr, "catalog_lookup", model=model):
+                    entry = self.router.mres.entry(model)
+                g0 = time.perf_counter()
                 if self.load is not None:
                     self.load.admit(col[model], count=len(idxs))
                     self.load.start(col[model], count=len(idxs))
@@ -348,6 +349,7 @@ class ServingEngine:
                     if self.load is not None:
                         self.load.finish(col[model], per_req_s,
                                          count=len(idxs))
+                gen_s = (time.perf_counter() - g0) / len(idxs)
                 for j, i in enumerate(idxs):
                     r, rq = routed[i]
                     # a rerouted request was SERVED by a different
@@ -362,7 +364,7 @@ class ServingEngine:
                         sim_latency_s=0.0 if (gen is None or err)
                         else per_req_s,
                         route_s=rq.route_s, analyzer_s=rq.analyzer_s,
-                        fallback=rq.fallback_kind,
+                        generate_s=gen_s, fallback=rq.fallback_kind,
                         rq=rq if (plans[i][1] == "admitted" and not err)
                         else None,
                         admission="failed" if err else plans[i][1],
@@ -402,11 +404,8 @@ class ServingEngine:
         reqs = list(requests)
         out: List[Optional[Response]] = [None] * len(reqs)
         tel = self.router.telemetry
-        tr = self.tracer
-        batch_span = tr.start_trace("submit", batch=len(reqs),
-                                    mode="batch") \
-            if tr is not None else NOOP_SPAN
-        with batch_span:
+        with span(self.tracer, "submit", batch=len(reqs),
+                  mode="batch") as batch_span:
             prefs_res = resolve_batch([r.prefs for r in reqs], len(reqs))
             miss = list(range(len(reqs)))
             if self.cache is not None:
@@ -447,7 +446,8 @@ class ServingEngine:
         decision, _, stats = self.router.route_batch(
             texts, requests[0].prefs)
         model = decision.model
-        entry = self.router.mres.entry(model)
+        with span(self.tracer, "catalog_lookup", model=model):
+            entry = self.router.mres.entry(model)
         tel = self.router.telemetry
         col = -1
         if self.load is not None:
@@ -456,6 +456,7 @@ class ServingEngine:
             self.load.ensure(len(names))
             self.load.admit(col, count=len(requests))
             self.load.start(col, count=len(requests))
+        g0 = time.perf_counter()
         gen, per_req_s, err = None, None, ""
         try:
             if entry.runner is not None:
@@ -470,6 +471,7 @@ class ServingEngine:
         finally:
             if self.load is not None:
                 self.load.finish(col, per_req_s, count=len(requests))
+        gen_s = (time.perf_counter() - g0) / len(requests)
         agg = stats["aggregate_sig"]
         out = [Response(
             request=r, model=model, sig=agg,
@@ -477,7 +479,7 @@ class ServingEngine:
             sim_latency_s=0.0 if (gen is None or err) else per_req_s,
             route_s=stats["route_s"] / len(requests),
             analyzer_s=stats["analyzer_s"] / len(requests),
-            fallback=decision.fallback_kind,
+            generate_s=gen_s, fallback=decision.fallback_kind,
             admission="failed" if err else "admitted",
             error=err) for i, r in enumerate(requests)]
         if tel is not None:

@@ -14,7 +14,8 @@ from repro.core.orchestrator import OptiRoute
 from repro.core.telemetry import Telemetry
 from repro.serving.async_engine import (REJECT_BACKLOG, REJECT_RATE,
                                         AsyncServingEngine, MicroBatcher,
-                                        TenantPolicy, TokenBucket)
+                                        TenantPolicy, TokenBucket,
+                                        window_stats)
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.load import LoadTracker
 from tests.conftest import make_entry
@@ -128,6 +129,29 @@ def test_microbatcher_deficit_resets_on_empty_queue():
     assert mb._deficit["slow"] == 0.0
     mb.offer("slow", "s1", 1.0)
     assert mb.take(1.0) == ["s1"]
+
+
+def test_microbatcher_queue_waits_on_a_fake_clock():
+    """Each taken item keeps its offer time; the window's waits run
+    from there to the start of its service, on the same clock."""
+    mb = MicroBatcher(max_batch=3, max_wait_s=0.005)
+    for t, item in ((0.000, "a0"), (0.001, "a1"), (0.004, "a2"),
+                    (0.006, "a3")):
+        mb.offer("acme", item, t)
+    stamped = mb.take_stamped(0.006)
+    assert stamped == [(0.000, "a0"), (0.001, "a1"), (0.004, "a2")]
+    start = 0.010                       # service starts after the take
+    assert [start - t for t, _ in stamped] == pytest.approx(
+        [0.010, 0.009, 0.006])
+    stats = window_stats([t for t, _ in stamped], start, mb.pending())
+    assert stats == pytest.approx({"size": 3, "wait_ms_sum": 25.0,
+                                   "wait_ms_max": 10.0, "backlog": 1})
+    # the left-over request makes the next window, its wait its own
+    (last,) = mb.take_stamped(0.020)
+    assert window_stats([last[0]], 0.021, mb.pending()) == pytest.approx(
+        {"size": 1, "wait_ms_sum": 15.0, "wait_ms_max": 15.0,
+         "backlog": 0})
+    assert window_stats([], 0.0, 0)["wait_ms_max"] == 0.0
 
 
 def test_microbatcher_intake_rejections_and_stats():

@@ -16,7 +16,7 @@ from repro.obs import (DeviceCostProfiler, Tracer, evaluate_rules,
                        trace_capture)
 from repro.obs.metrics import Counter, Gauge, LogHistogram
 from repro.obs.slo import SLOEvaluator
-from repro.obs.trace import NOOP_SPAN
+from repro.obs.trace import NOOP_SPAN, span
 
 
 def _ev(ts=1.0, model="m0", fallback="", route_s=0.01, cost=2.0):
@@ -97,6 +97,31 @@ def test_disabled_tracer_is_noop(tmp_path):
     assert tr.stats() == {"spans_total": 0, "spans_retained": 0,
                           "max_spans": 16384}
     assert tr.export_jsonl(tmp_path / "t.jsonl") == 0
+
+
+def test_span_helper_without_tracer_is_profiler_only():
+    """``span`` with no tracer, or a disabled one, records nothing and
+    still behaves as a span (``set``, ids) for the code inside it."""
+    for tr in (None, Tracer(enabled=False)):
+        with span(tr, "route_step", path="dense") as sp:
+            sp.set(compiles=0)
+        assert sp is not NOOP_SPAN
+        assert sp.trace_id == "" and sp.attrs == {}
+        if tr is not None:
+            assert tr.stats()["spans_total"] == 0
+
+
+def test_span_helper_with_tracer_records_into_the_ring():
+    tr = Tracer()
+    stats = {"size": 2, "wait_ms_sum": 3.5, "wait_ms_max": 2.0,
+             "backlog": 0}
+    with span(tr, "window", stats=stats) as w:
+        with span(tr, "submit", batch=2) as sub:
+            assert tr.current() is sub
+    assert w.stats == stats and sub.stats == {}
+    assert sub.parent_id == w.span_id and sub.trace_id == w.trace_id
+    assert w.attrs == stats and sub.attrs == {"batch": 2}
+    assert [s.name for s in tr.spans()] == ["submit", "window"]
 
 
 def test_span_ring_bounded_and_monotonic():

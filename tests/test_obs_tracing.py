@@ -135,11 +135,13 @@ def test_batch_trace_tree_spans_whole_pipeline():
     tree = tr.summary_tree(submit.trace_id)
     assert tree["name"] == "submit"
     assert tree["attrs"] == {"batch": 5, "mode": "interactive"}
-    names = _child_names(tree)
-    for stage in ("cache_lookup", "analyze", "route_step",
-                  "admission", "generate"):
-        assert stage in names, f"missing {stage} in {names}"
-    (rs,) = [c for c in tree["children"] if c["name"] == "route_step"]
+    assert _child_names(tree) == ["admission", "cache_lookup",
+                                  "generate", "route_all"]
+    (ra,) = [c for c in tree["children"] if c["name"] == "route_all"]
+    assert _child_names(ra) == ["analyze", "route_step"]
+    (gen,) = [c for c in tree["children"] if c["name"] == "generate"]
+    assert set(_child_names(gen)) == {"catalog_lookup"}
+    (rs,) = [c for c in ra["children"] if c["name"] == "route_step"]
     assert rs["attrs"]["batch"] == 5
     assert rs["attrs"]["q_bucket"] >= 5
     assert rs["attrs"]["path"] in ("dense", "sharded", "ivf")
