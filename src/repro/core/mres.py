@@ -157,7 +157,9 @@ class MRES:
 
     def __init__(self):
         self._entries: List[ModelEntry] = []
-        self._names: set = set()
+        # name -> row in _entries; entries are only appended, so a row
+        # never moves and matches snapshot()'s name order
+        self._index: Dict[str, int] = {}
         self._emb: Optional[np.ndarray] = None
         self._tt_matrix: Optional[np.ndarray] = None
         self._dm_matrix: Optional[np.ndarray] = None
@@ -181,21 +183,21 @@ class MRES:
         catalog untouched."""
         entries = list(entries)
         with self._lock:
-            seen = set(self._names)
-            for entry in entries:
+            new: Dict[str, int] = {}
+            for row, entry in enumerate(entries, len(self._entries)):
                 entry.validate()
-                if entry.name in seen:
+                if entry.name in self._index or entry.name in new:
                     raise ValueError(f"duplicate model {entry.name!r}")
-                seen.add(entry.name)
-            self._names = seen
+                new[entry.name] = row
+            self._index.update(new)
             self._entries.extend(entries)
             self._dirty = True
 
     def _register_locked(self, entry: ModelEntry) -> None:
         entry.validate()
-        if entry.name in self._names:
+        if entry.name in self._index:
             raise ValueError(f"duplicate model {entry.name!r}")
-        self._names.add(entry.name)
+        self._index[entry.name] = len(self._entries)
         self._entries.append(entry)
         self._dirty = True
 
@@ -206,10 +208,7 @@ class MRES:
             self._dirty = True
 
     def _by_name(self, name: str) -> ModelEntry:
-        for e in self._entries:
-            if e.name == name:
-                return e
-        raise KeyError(name)
+        return self._entries[self._index[name]]
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -221,6 +220,13 @@ class MRES:
     def entry(self, name: str) -> ModelEntry:
         with self._lock:
             return self._by_name(name)
+
+    def column(self, name: str) -> int:
+        """The model's row: its column in the load tracker and bandit
+        arrays, and its position in ``snapshot()``'s names.  Raises
+        ``KeyError`` on an unknown name."""
+        with self._lock:
+            return self._index[name]
 
     # ---------------- embeddings & mask caches ----------------
     def _refresh_locked(self) -> None:

@@ -350,8 +350,7 @@ class OptiRoute:
             sub_ep = None if extra_penalty is None else \
                 np.asarray(extra_penalty, np.float32)[fresh]
             names = self.mres.snapshot()[1]
-            col = {m: j for j, m in enumerate(names)}
-            midx = np.array([col[rq.model] for rq in sub])
+            midx = np.array([self.mres.column(rq.model) for rq in sub])
             X = np.stack([rq.task_vector for rq in sub])
             if self.reward_shaper is not None:
                 rewards = self.reward_shaper.shape(sub_q, midx, sub_ep)

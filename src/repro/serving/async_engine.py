@@ -469,8 +469,7 @@ class AsyncServingEngine:
         if model not in self._stream_state:
             col = 0
             if eng.load is not None:
-                names = eng.router.mres.snapshot()[1]
-                col = {m: j for j, m in enumerate(names)}[model]
+                col = eng.router.mres.column(model)
             cb = ContinuousBatcher(
                 entry.runner.cfg, entry.runner.params,
                 slots=self._stream_slots, ctx_len=self._stream_ctx_len,

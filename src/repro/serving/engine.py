@@ -54,6 +54,18 @@ from repro.obs.trace import NOOP_SPAN, span
 from repro.serving.load import LoadTracker, plan_admission
 
 
+class _Columns:
+    """``name -> catalog column`` through the registry's index, read
+    by key as the mapping ``plan_admission`` takes."""
+    __slots__ = ("_column",)
+
+    def __init__(self, mres):
+        self._column = mres.column
+
+    def __getitem__(self, name: str) -> int:
+        return self._column(name)
+
+
 @dataclass
 class Request:
     text: str
@@ -270,11 +282,9 @@ class ServingEngine:
                 rq.cache_key = np.asarray(cache_keys[j])
                 rq.cache_fp = int(cache_fps[j])
         routed = list(zip(requests, routed_q))
-        col: Dict[str, int] = {}
+        col = _Columns(self.router.mres)
         if self.load is not None:
-            names = self.router.mres.snapshot()[1]
-            col = {m: j for j, m in enumerate(names)}
-            self.load.ensure(len(names))
+            self.load.ensure(len(self.router.mres.snapshot()[1]))
         plans = []
         tel = self.router.telemetry
         # pending placements from EARLIER requests in this same batch:
@@ -451,9 +461,8 @@ class ServingEngine:
         tel = self.router.telemetry
         col = -1
         if self.load is not None:
-            names = self.router.mres.snapshot()[1]
-            col = {m: j for j, m in enumerate(names)}[model]
-            self.load.ensure(len(names))
+            col = self.router.mres.column(model)
+            self.load.ensure(len(self.router.mres.snapshot()[1]))
             self.load.admit(col, count=len(requests))
             self.load.start(col, count=len(requests))
         g0 = time.perf_counter()

@@ -658,3 +658,65 @@ def test_oversized_tracker_routes_and_serves():
     out = engine.submit([Request(text="q", prefs="balanced", id=0,
                                  deadline_ms=60_000.0)])
     assert out[0].admission in ADMISSION_KINDS
+
+
+@pytest.mark.parametrize("mode", ["interactive", "batch"])
+def test_load_counts_land_on_the_snapshot_column(mode):
+    """Admission counts go to the routed model's column in snapshot()'s
+    name order (interactive: ``_route_and_serve`` with deadline
+    admission; batch: ``_serve_batch_group``).  The catalog is
+    registered out of name order, so a column is not the name's digit."""
+    import types
+    from repro.core.orchestrator import OptiRoute
+    from repro.serving.engine import Request, ServingEngine
+    from tests.test_routing_batch import StubAnalyzer
+
+    def entry(i):
+        # accuracy bought with latency and cost: profiles disagree
+        return make_entry(f"m{i}", accuracy=0.5 + 0.08 * i,
+                          latency_ms=50.0 + 40 * i, cost=1.0 + i,
+                          generalist=True)
+
+    m = MRES()
+    m.register_many([entry(i) for i in (4, 1, 5, 0, 3)])
+    m.register(entry(2))
+    lt = LoadTracker(len(m), capacity=2.0, default_service_s=0.05)
+    engine = ServingEngine(OptiRoute(m, StubAnalyzer(),
+                                     telemetry=Telemetry(), load=lt,
+                                     load_weight=1.0))
+    names = m.snapshot()[1]
+    seen = []                                # (model, group size, q, f)
+
+    class Probe:
+        cfg = _BoomCfg()
+
+        def __init__(self, name):
+            self.name = name
+
+        def generate(self, toks, max_new=8):
+            q, f, _, _ = lt.snapshot()
+            seen.append((self.name, toks.shape[0], q.copy(), f.copy()))
+            return types.SimpleNamespace(
+                tokens=np.zeros((toks.shape[0], max_new), np.int32),
+                sim_latency_s=0.01 * toks.shape[0])
+
+    for n in names:
+        m.entry(n).runner = Probe(n)
+    profiles = ["accuracy-first", "cost-effective", "latency-first"]
+    reqs = [Request(text=f"q{i}", prefs=profiles[i % 3], id=i,
+                    deadline_ms=10_000.0) for i in range(9)]
+    out = engine.submit(reqs, mode=mode)
+    assert [r.admission for r in out] == ["admitted"] * len(reqs)
+    assert len(seen) == len({r.model for r in out})
+    assert len(seen) > 1 if mode == "interactive" else len(seen) == 1
+    assert sum(k for _, k, _, _ in seen) == len(reqs)
+    for name, k, q, f in seen:               # in flight while generating
+        want = [0] * len(names)
+        want[names.index(name)] = k
+        assert q.tolist() == [0] * len(names)
+        assert f.tolist() == want
+    q, f, _, ewma = lt.snapshot()            # drained, EWMA folded
+    assert (q == 0).all() and (f == 0).all()
+    served = {names.index(n) for n, _, _, _ in seen}
+    for j in range(len(names)):
+        assert (ewma[j] != pytest.approx(0.05)) == (j in served), j
