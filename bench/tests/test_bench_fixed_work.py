@@ -32,8 +32,8 @@ def _requests(tr):
 
 
 @pytest.mark.parametrize("name", sorted(
-    {w["traffic"] for w in SPEC["workloads"]
-     if "pool_seed" in _mix(w["traffic"])}))
+    f.stem for f in (_BENCH / "traffic").glob("*.json")
+    if "pool_seed" in _mix(f.stem)))
 def test_pooled_mix_offers_the_same_requests_in_another_order(name):
     a, b = (traffic.build(_mix(name), s, 12.0) for s in SEEDS)
     np.testing.assert_array_equal(a.arrivals, b.arrivals)

@@ -22,21 +22,30 @@ sys.path[:0] = [str(_BENCH), str(ROOT / "src")]
 from benchlib import check, harness  # noqa: E402
 
 
-# a cell kept under bench/ whose limits still wait for their readings on
-# the chip, and so is not yet in BENCHMARK.json: configuration, mix and
-# end-to-end metrics
-PENDING = {"served-qwen2-open": ("tier4-qwen2", "chat-open",
-                                 {"request_p95_ms": "ms", "setup_s": "s"})}
+# a cell kept under bench/ and not yet in BENCHMARK.json (PERF.md §7
+# says what it waits for): configuration, mix, end-to-end metrics, and
+# per-layer metrics with their sources
+PENDING = {"served-qwen2-open": (
+    "tier4-qwen2", "chat-open",
+    {"request_p50_ms": "ms", "setup_s": "s"},
+    {"request_tail_p95_ms": ("ms", "host_clock"),
+     "prefill_ms": ("ms", "device_trace"),
+     "decode_step_ms": ("ms", "device_trace"),
+     "decode_roofline": ("%", "device_trace"),
+     "decode_mfu": ("%", "device_trace"),
+     "idle_share.served": ("%", "device_trace")})}
 
 
 def _cell(name):
     if name not in PENDING:
         return harness.load_cell(name, ROOT)
-    conf, mix, e2e = PENDING[name]
+    conf, mix, e2e, layer = PENDING[name]
     return harness.Cell(
         name, json.loads((_BENCH / "configs" / f"{conf}.json").read_text()),
         json.loads((_BENCH / "traffic" / f"{mix}.json").read_text()), 1,
-        [{"name": n, "unit": u} for n, u in e2e.items()], [])
+        [{"name": n, "unit": u} for n, u in e2e.items()],
+        [{"name": n, "unit": u, "source": s}
+         for n, (u, s) in layer.items()])
 
 
 def tiny_cell(name):
@@ -141,10 +150,128 @@ def test_altered_token_is_caught(name, cache, monkeypatch):
         res["checks"]["logit_gap_mean"]["limit"]
 
 
+def _stale_cache(sysm):
+    """The backend's decode step returns its cache unchanged: no step
+    writes the keys and values of the token it read."""
+    real = sysm.runner._decode
+
+    def stale(params, cache, batch):
+        logits, tok, _ = real(params, cache, batch)
+        return logits, tok, cache
+
+    sysm.runner._decode = stale
+
+
+def _half_batch(sysm):
+    """The backend runs only the first half of each group it is handed
+    and gives the other half the first half's tokens."""
+    real = sysm.runner.generate
+
+    def half(tokens, max_new=16):
+        out = real(tokens[:(len(tokens) + 1) // 2], max_new=max_new)
+        out.tokens = np.resize(out.tokens, (len(tokens),
+                                            out.tokens.shape[1]))
+        return out
+
+    sysm.runner.generate = half
+
+
+@pytest.mark.parametrize("fault", [_stale_cache, _half_batch],
+                         ids=["stale_cache", "half_batch"])
+def test_served_fault_is_caught(fault, cache):
+    res = _run("served-qwen2-open", 13, cache=cache, tamper=fault)
+    assert not res["correct"]
+    assert res["checks"]["logit_gap_mean"]["value"] > \
+        res["checks"]["logit_gap_mean"]["limit"]
+
+
 def test_trace_run_reports_device_window(cache):
     res = _run(CELLS[0], 8, cache=cache, trace=True)
     assert res["device"]["window_s"] > 0
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_trace_records_no_python_calls(tmp_path):
+    """The traced window holds XLA's events and the benchmark's
+    annotations, not an event per Python call (named ``$file:line``),
+    which would slow the host path the trace measures."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+
+    hooks = harness.TraceHooks(str(tmp_path))
+    hooks.start()
+    with jax.profiler.TraceAnnotation("bench.probe"):
+        sum(abs(i) for i in range(100))
+        jax.numpy.ones(3).block_until_ready()
+    hooks.end()
+    hooks.write()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for p in ProfileData.from_file(path).planes
+             for line in p.lines for e in line.events]
+    assert "bench.probe" in names
+    assert not [n for n in names if n.startswith("$")]
+
+
+def test_trace_is_written_only_once_the_run_asks(tmp_path):
+    """Closing the traced window collects the trace but writes nothing:
+    writing it out stalls the serving thread, so the run writes it once
+    every request of the window has its answer."""
+    import jax
+
+    hooks = harness.TraceHooks(str(tmp_path))
+    hooks.start()
+    jax.numpy.ones(3).block_until_ready()
+    hooks.end()
+    assert not list(tmp_path.rglob("*.xplane.pb"))
+    hooks.write()
+    assert len(list(tmp_path.rglob("*.xplane.pb"))) == 1
+
+
+def test_request_median_takes_on_chip_answers_and_failures():
+    """``request_p50_ms`` is the median, from each request's due time,
+    over the requests the on-chip backend answered with tokens and the
+    failed ones; answers from models off the chip do not count."""
+    from types import SimpleNamespace as NS
+    from benchlib import drive
+
+    def rec(due, wait, model, tokens=True):
+        ans = NS(model=model, admission="admitted",
+                 tokens=np.zeros(4, np.int32) if tokens else None)
+        return drive.Record(spec=None, due=due, done=due + wait, answer=ans)
+
+    t0 = 100.0
+    chip = [rec(t0 + i, w, "qwen2-1.5b")
+            for i, w in enumerate([0.9, 2.5, 1.3, 4.0, 1.1])]
+    off = [rec(t0 + i, w, "mamba2-1.3b", tokens=False)
+           for i, w in enumerate([0.01, 0.02, 0.015, 0.03])]
+    failed = drive.Record(spec=None, due=t0 + 2, done=t0 + 9.0,
+                          error="RuntimeError: planted")
+    run = drive.Run(chip + off + [failed], t0, t0 + 51.0, [])
+    res = harness.outcomes(NS(on_chip={"qwen2-1.5b"}), run)
+    waits = np.array([0.9, 2.5, 1.3, 4.0, 1.1, 7.0]) * 1e3
+    assert res["on_chip_requests"] == 5 and res["failed"] == 1
+    assert res["request_p50_ms"] == pytest.approx(np.percentile(waits, 50))
+    assert res["request_p95_ms"] == pytest.approx(np.percentile(waits, 95))
+    assert res["decide_p50_ms"] == pytest.approx(np.percentile(
+        np.r_[waits, np.array([0.01, 0.02, 0.015, 0.03]) * 1e3], 50))
+
+
+def test_traced_served_run_reads_its_host_metrics(cache):
+    """A traced run of the served cell reads every per-layer metric it
+    can from a CPU run: the client's tail.  The CPU's trace has no
+    device plane, so the device readers find nothing and their metrics
+    are left out (``test_bench_trace`` reads them on the reduction of
+    a traced run of this tiny cell recorded on the chip)."""
+    cell = tiny_cell("served-qwen2-open")
+    assert len(cell.per_layer) == 6
+    res = _run("served-qwen2-open", 12, cache=cache, trace=True)
+    assert res["correct"], res["checks"]
+    device = {m["name"] for m in cell.per_layer
+              if m["source"] == "device_trace"}
+    assert set(res["metrics"]) == {m["name"] for m in cell.per_layer} \
+        - device
+    assert res["metrics"]["request_tail_p95_ms"]["value"] > 0
 
 
 def _bench_cmd(cwd, env_extra=None):

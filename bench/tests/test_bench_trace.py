@@ -179,3 +179,48 @@ def test_readers_find_nothing_in_an_empty_trace():
                  "prefill_ms", "decode_step_ms", "decode_roofline",
                  "decode_mfu"):
         assert harness.load_reader(name)(ctx) is None, name
+
+
+# ----------------------------------------------------------------------
+# the served cell's device readers on a traced run recorded on the chip
+# ----------------------------------------------------------------------
+
+SERVED = "served-qwen2-open"
+# the reduction of a traced run of ``tiny_cell(SERVED)`` (seed 7, 3-s
+# window) on a TPU v5e: its backend's dimensions, each device's programs
+# and busy time, the ``bench.*`` spans, the traced window, the shapes of
+# the traced ``generate`` calls, and the metrics that run reported
+SERVED_RECORDED = _BENCH / "tests" / "data" / "served_tiny_v5e.json"
+
+
+# the served cell's per-layer metrics read from the device's trace
+SERVED_DEVICE = ["prefill_ms", "decode_step_ms", "decode_roofline",
+                 "decode_mfu", "idle_share.served"]
+
+
+@pytest.fixture(scope="module")
+def served_recorded():
+    import json
+    rec = json.loads(SERVED_RECORDED.read_text())
+    ev = lambda t: Event(*t)                                # noqa: E731
+    devices = {k: Device(modules=[ev(m) for m in d["modules"]],
+                         busy_ns=d["busy_ns"])
+               for k, d in rec["devices"].items()}
+    red = Reduced(devices, [ev(s) for s in rec["spans"]], 0.0, 0.0,
+                  window=rec["window_ns"])
+    ctx = _ctx(red, system=SimpleNamespace(backend=rec["backend"]))
+    ctx.generate_calls = [(np.zeros(s, np.int32),)
+                          for s in rec["generate_calls"]]
+    return ctx, rec["metrics"]
+
+
+@pytest.mark.parametrize("name", SERVED_DEVICE)
+def test_served_device_reader_reads_the_recorded_run(name, served_recorded):
+    """Each device reader of the served cell finds its value in the
+    recorded run, the value that run reported; shares stay under 100%."""
+    ctx, reported = served_recorded
+    value = harness.load_reader(name)(ctx)
+    assert value is not None and value > 0
+    assert value == pytest.approx(reported[name], rel=1e-9)
+    if name.endswith("roofline") or "mfu" in name or "share" in name:
+        assert value < 100
