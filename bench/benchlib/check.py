@@ -27,7 +27,7 @@ Numbers compared (each has its limit in the configuration's
   the few near-ties a sample happens to hold.)
 
 The sample is drawn from ``--seed`` among the requests the window
-finished.
+finished; the served sample always holds the longest answer.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib import inputs, reference, system as sysmod
+from benchlib import backend, inputs, reference, system as sysmod
 from benchlib.tokenize import Tokenizer
 from benchlib.weights import analyzer_tokens
 
@@ -76,10 +76,17 @@ def _routed(answer):
     return getattr(answer, "rq", answer) if answer is not None else None
 
 
-def sample(run, seed: int, want: int, pick) -> List:
+def sample(run, seed: int, want: int, pick, longest=None) -> List:
+    """Up to ``want`` finished records that ``pick`` takes, drawn from
+    ``seed``; with ``longest`` (a record's length), the longest of them
+    is always among the sample."""
     recs = [r for r in run.records if r.done is not None and pick(r)]
     rng = np.random.default_rng([seed, 3])
     idx = rng.permutation(len(recs))[:want]
+    if longest is not None and len(recs) and len(idx):
+        top = max(range(len(recs)), key=lambda i: longest(recs[i]))
+        if top not in idx:
+            idx[-1] = top
     return [recs[i] for i in sorted(idx)]
 
 
@@ -145,17 +152,17 @@ def served_gap_mean(sysm, recs, *, control: bool = False) -> float:
     for the control, of the tokens the control puts first at the same
     positions of the same prompts and served tokens)."""
     m = sysm.backend
+    logits = backend.of(m).logits
     prompts = prompt_tokens(sysm, [r.spec.text for r in recs])
     gaps = []
     for p, r in zip(prompts, recs):
         served = np.asarray(r.answer.tokens, np.int32)
         seq = np.concatenate([p, served[:-1]])
-        ref = reference.decoder_logits(sysm.backend_weights, m, seq)
+        ref = logits(sysm.backend_weights, m, seq)
         start = len(p) - 1
         picked = jnp.asarray(served)
         if control:
-            ctl = reference.decoder_logits(sysm.backend_weights, m, seq,
-                                           control=True)
+            ctl = logits(sysm.backend_weights, m, seq, control=True)
             picked = _argmax_rows(ctl)[start:start + len(served)]
         gaps.append(np.asarray(_gaps(ref, picked, start)))
     return float(np.concatenate(gaps).mean())
@@ -183,7 +190,8 @@ def check_run(sysm, run, seed: int, *,
     if sysm.runner is not None:
         served = sample(run, seed, SERVED_SAMPLE, lambda r: (
             getattr(r.answer, "tokens", None) is not None
-            and r.answer.model in sysm.on_chip))
+            and r.answer.model in sysm.on_chip),
+            longest=lambda r: len(r.answer.tokens))
         # a backend that served nothing in the window cannot be shown
         # correct: that reads as a failed comparison
         out["logit_gap_mean"] = {

@@ -1,12 +1,10 @@
 """Operations and bytes that a step needs, from its shapes.
 
 The counting rule: count the work the configuration needs at its
-declared dtypes, not what today's code happens to move.
+declared dtypes, not what today's code happens to move.  A served
+backend's decode step is counted by its module's ``decode_step``
+(``bench/backends/<architecture>.py``).
 
-* The decode step reads every weight once in the compute dtype (bf16),
-  reads the keys and values of the positions it attends to, and writes
-  one position of keys and values per layer.  It does not read the fp32
-  master copy, and the padded rows of the embedding are not weights.
 * The decision reads the analyzer's weights once (fp32), the fp32 unit
   rows of the catalog once, each distinct filter row it uses once
   (one byte per entry), gathers the raw metric rows of its candidates,
@@ -28,59 +26,6 @@ class Counts:
 
     def __add__(self, other: "Counts") -> "Counts":
         return Counts(self.flops + other.flops, self.nbytes + other.nbytes)
-
-
-# ----------------------------------------------------------------------
-# served backend: a dense decoder with GQA, QKV bias and SwiGLU
-# ----------------------------------------------------------------------
-
-def decoder_layer_params(d: int, n_heads: int, n_kv_heads: int, d_ff: int,
-                         qkv_bias: bool) -> int:
-    hd = d // n_heads
-    q, kv = n_heads * hd, n_kv_heads * hd
-    n = d * q + 2 * d * kv + q * d + 3 * d * d_ff + 2 * d
-    if qkv_bias:
-        n += q + 2 * kv
-    return n
-
-
-def decoder_params(m: dict) -> int:
-    """All weights of the model: layers, final norm, tied embedding."""
-    per = decoder_layer_params(m["hidden_size"], m["num_attention_heads"],
-                               m["num_key_value_heads"],
-                               m["intermediate_size"], m["qkv_bias"])
-    return (m["num_hidden_layers"] * per + m["hidden_size"]
-            + m["vocab_size"] * m["hidden_size"])
-
-
-def decoder_matmul_params(m: dict) -> int:
-    """Weights that take part in a matmul per token: the layers' and
-    the output head's (the embedding lookup is a gather)."""
-    per = decoder_layer_params(m["hidden_size"], m["num_attention_heads"],
-                               m["num_key_value_heads"],
-                               m["intermediate_size"], m["qkv_bias"])
-    return m["num_hidden_layers"] * per + m["vocab_size"] * m["hidden_size"]
-
-
-def decode_step(m: dict, batch: int, attended: int) -> Counts:
-    """One greedy decode step of ``batch`` sequences, each attending to
-    ``attended`` positions (its cached prefix and itself)."""
-    d, L = m["hidden_size"], m["num_hidden_layers"]
-    hd = d // m["num_attention_heads"]
-    q_dim = m["num_attention_heads"] * hd
-    kv_dim = m["num_key_value_heads"] * hd
-    flops = (2.0 * batch * decoder_matmul_params(m)
-             + 4.0 * batch * L * q_dim * attended)
-    nbytes = (BF16 * decoder_params(m)
-              + BF16 * L * batch * attended * 2 * kv_dim
-              + BF16 * L * batch * 2 * kv_dim
-              + batch * (I32 + I32))
-    return Counts(flops, nbytes)
-
-
-def decode_flops_per_token(m: dict) -> float:
-    """Model operations per generated token (matmul weights only)."""
-    return 2.0 * decoder_matmul_params(m)
 
 
 # ----------------------------------------------------------------------

@@ -7,7 +7,6 @@ under ``bench/metrics/`` found by their names in ``BENCHMARK.json``.
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import pathlib
 import shutil
@@ -20,7 +19,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from benchlib import check, drive, peaks, system as sysmod, traffic
+from benchlib import backend, check, drive, peaks, system as sysmod, traffic
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -47,6 +46,8 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
     config = json.loads((root / conf["file"]).read_text())
+    if "backend" in config:
+        backend.of(config["backend"])       # UnknownArchitecture if none
     mix = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json")
                      .read_text())
 
@@ -225,11 +226,13 @@ def warm_up(sysm, tr: traffic.Traffic) -> None:
         sysm.engine.submit([drive.make_request(sysm, s, prefs)
                             for s in specs[:b]])
     if sysm.runner is not None:
-        # every group size the backend can be handed in one window
+        # every answer length of the traffic x every group size the
+        # backend can be handed in one window
         prompts = check.prompt_tokens(sysm, [s.text for s in specs])
-        for g in range(1, mix["max_batch"] + 1):
-            toks = np.resize(prompts, (g, prompts.shape[1]))
-            sysm.runner.generate(toks, max_new=mix["max_new"])
+        for max_new in sorted({s.max_new for s in specs}):
+            for g in range(1, mix["max_batch"] + 1):
+                toks = np.resize(prompts, (g, prompts.shape[1]))
+                sysm.runner.generate(toks, max_new=max_new)
 
 
 # ----------------------------------------------------------------------
@@ -241,9 +244,15 @@ def _pct_ms(lat: List[float], q: float) -> float:
 
 
 def outcomes(sysm, run: drive.Run) -> dict:
-    """Per-request outcome: attempted, failed, latencies, on-chip."""
+    """Per-request outcome: attempted, failed, latencies, on-chip.
+
+    The decision's latencies are over the requests that end at the
+    decision: all but those the on-chip backend answered.  The
+    request's latencies are over those the on-chip backend answered,
+    with the failed ones where the cell has such a backend.  A failed
+    request counts in both with the time it waited."""
     att = fail = 0
-    lat_all, lat_chip = [], []
+    lat_dec, lat_chip = [], []
     done_in, tok_in = 0, 0
     chip_n = 0
     for r in run.records:
@@ -258,7 +267,8 @@ def outcomes(sysm, run: drive.Run) -> dict:
             and getattr(ans, "tokens", None) is not None
         if not ok:
             fail += 1
-        lat_all.append(waited)
+        if not on_chip:
+            lat_dec.append(waited)
         if on_chip or (not ok and sysm.on_chip):
             lat_chip.append(waited)
         if on_chip:
@@ -269,8 +279,8 @@ def outcomes(sysm, run: drive.Run) -> dict:
                 tok_in += len(ans.tokens)
     secs = run.t1 - run.t0
     return {"attempted": att, "failed": fail,
-            "decide_p50_ms": _pct_ms(lat_all, 50),
-            "decide_p95_ms": _pct_ms(lat_all, 95),
+            "decide_p50_ms": _pct_ms(lat_dec, 50),
+            "decide_p95_ms": _pct_ms(lat_dec, 95),
             "decisions_per_s": done_in / secs,
             "request_p50_ms": _pct_ms(lat_chip, 50),
             "request_p95_ms": _pct_ms(lat_chip, 95),
@@ -311,12 +321,8 @@ class Context:
 
 
 def load_reader(name: str) -> Callable:
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return backend.import_file(BENCH / "metrics" / f"{name}.py",
+                               "bench_metric_").read
 
 
 # ----------------------------------------------------------------------
@@ -339,11 +345,13 @@ def enable_cache() -> str:
 
 
 def prepare(cell: Cell, seed: int, seconds: float,
-            cache_dir: Optional[pathlib.Path] = None):
-    """Build the system and the traffic from ``seed`` and warm up."""
+            cache_dir: Optional[pathlib.Path] = None, warm: bool = True):
+    """Build the system and the traffic from ``seed`` and warm up (a
+    process that has already run the same shapes may skip it)."""
     sysm = sysmod.build(cell.config, seed, cache_dir or ROOT / ".bench_cache")
     tr = traffic.build(cell.mix, seed, seconds)
-    warm_up(sysm, tr)
+    if warm:
+        warm_up(sysm, tr)
     return sysm, tr
 
 
@@ -389,7 +397,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
          generator_late_ms_p99=float(np.percentile(late, 99)),
          generator_late_ms_max=float(late.max()),
          attempted=res["attempted"], failed=res["failed"],
+         decide_p50_ms=res["decide_p50_ms"],
          decide_p95_ms=res["decide_p95_ms"],
+         request_p95_ms=res["request_p95_ms"],
          on_chip_share=res["on_chip_share"],
          on_chip_requests=res["on_chip_requests"],
          routed_models=len(set(rows)),

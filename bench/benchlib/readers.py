@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from benchlib import counts
+from benchlib import backend
 from benchlib.trace import Event, Reduced
 
 ROUTE_PROGRAM = "analyze_route_step"
@@ -82,9 +82,10 @@ def decode_least_s(ctx, batch: int, prompt: int, steps: int) -> float:
     """Least time of ``steps`` decode steps after a ``prompt``-token
     prefill, by the roofline of the chip."""
     m = ctx.system.backend
+    step = backend.of(m).decode_step
     tot = 0.0
     for j in range(steps):
-        c = counts.decode_step(m, batch, prompt + j + 1)
+        c = step(m, batch, prompt + j + 1)
         tot += max(c.flops / ctx.peak.bf16_flops,
                    c.nbytes / ctx.peak.hbm_bytes_per_s)
     return tot

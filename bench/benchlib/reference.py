@@ -13,14 +13,14 @@ configuration states.
   outputs: hierarchical task-type x domain filter, masked cosine top-k,
   blend of the user's weights with the metric rows at the candidates,
   and the fallback ladder (task-type only, generalists, any).
-* ``decoder_logits``: the served backend's causal decoder (RMSNorm with
-  a ``1 + w`` scale, GQA with QKV bias and half-split RoPE, SwiGLU,
-  tied output head) over one sequence, one layer at a time.
+
+A served backend's reference is its module's ``logits``
+(``bench/backends/<architecture>.py``).
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Sequence
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,7 @@ RAW_AXES = (("accuracy", "accuracy", True), ("latency_ms", "speed", False),
             ("creativity", "creativity", True))
 
 
-def _quantize_fp8(w, dt):
+def quantize_fp8(w, dt):
     """fp8 (e4m3) copy of a weight matrix with one scale per output
     column, returned dequantized in ``dt``."""
     s = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 448.0 + 1e-12
@@ -79,7 +79,7 @@ def analyzer_logits(params, tokens, n_heads, precision, dtype, fp8=False):
     """tokens (B, L) -> (task-type logits, domain logits, complexity)."""
     dt = jnp.dtype(dtype)
     p = jax.tree_util.tree_map(
-        lambda a: _quantize_fp8(a, dt) if fp8 and a.ndim == 2
+        lambda a: quantize_fp8(a, dt) if fp8 and a.ndim == 2
         else a.astype(dt), params)
     B, L = tokens.shape
     d = p["embed"].shape[1]
@@ -282,84 +282,3 @@ def route_errors(cat: Catalog, W, tt_idx, dm_idx, cx, conf, decided: Dict,
             jnp.asarray(np.asarray(decided["similarity"])[sl], jnp.float32),
             k=min(k, cat.n), sure=sure)))
     return np.concatenate(errs) if errs else np.zeros(0)
-
-
-# ----------------------------------------------------------------------
-# served decoder
-# ----------------------------------------------------------------------
-
-def _rms(x, w):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + 1e-6)
-    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
-
-
-def _rope(x, theta):
-    L, H, hd = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None]
-    sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def _decoder(tokens, w, *, n_heads, n_kv, theta, vocab, precision, dtype,
-             fp8):
-    dt = jnp.dtype(dtype)
-    L = tokens.shape[0]
-    d = w["embed"].shape[1]
-    hd = d // n_heads
-    g = n_heads // n_kv
-    causal = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
-
-    def cast(a):
-        return _quantize_fp8(a, dt) if fp8 else a.astype(dt)
-
-    def mm(a, b):
-        return jnp.matmul(a, cast(b), precision=precision)
-
-    def layer(x, lp):
-        h = _rms(x, lp["ln_mix"])
-        q = (mm(h, lp["wq"]) + lp["bq"].astype(dt)).reshape(L, n_heads, hd)
-        k = (mm(h, lp["wk"]) + lp["bk"].astype(dt)).reshape(L, n_kv, hd)
-        v = (mm(h, lp["wv"]) + lp["bv"].astype(dt)).reshape(L, n_kv, hd)
-        q, k = _rope(q, theta), _rope(k, theta)
-        s = jnp.einsum("lkgd,mkd->kglm", q.reshape(L, n_kv, g, hd), k,
-                       precision=precision).astype(jnp.float32)
-        s = jnp.where(causal, s / math.sqrt(hd), NEG)
-        a = jax.nn.softmax(s, -1).astype(dt)
-        o = jnp.einsum("kglm,mkd->lkgd", a, v, precision=precision)
-        x = x + mm(o.reshape(L, d), lp["wo"])
-        h = _rms(x, lp["ln_mlp"])
-        return x + mm(jax.nn.silu(mm(h, lp["wg"])) * mm(h, lp["wi"]),
-                      lp["wo_mlp"]), None
-
-    layers = {k: w[k] for k in _LAYER_KEYS}
-    x = w["embed"][tokens].astype(dt)
-    x, _ = jax.lax.scan(layer, x, layers)
-    h = _rms(x, w["ln_f"])
-    head = w["embed"][:vocab]
-    return jnp.matmul(h, cast(head).T if fp8 else head.astype(dt).T,
-                      precision=precision).astype(jnp.float32)
-
-
-_LAYER_KEYS = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "wg", "wi",
-               "wo_mlp", "ln_mix", "ln_mlp")
-_decoder_jit = jax.jit(_decoder, static_argnames=(
-    "n_heads", "n_kv", "theta", "vocab", "precision", "dtype", "fp8"))
-
-
-def decoder_logits(weights: Dict, m: dict, tokens: np.ndarray, *,
-                   control: bool = False) -> jnp.ndarray:
-    """Logits (L, vocab) of one sequence.  ``weights`` holds the layers
-    stacked on a leading axis (``weights.decoder_weights``); the scan
-    reads one layer at a time.  ``control``: fp8 weights with a scale
-    per output column and bf16 activations, one step below the bf16
-    compute the configuration states."""
-    return _decoder_jit(
-        jnp.asarray(tokens, jnp.int32), weights,
-        n_heads=m["num_attention_heads"], n_kv=m["num_key_value_heads"],
-        theta=float(m["rope_theta"]), vocab=m["vocab_size"],
-        precision="default" if control else "highest",
-        dtype="bfloat16" if control else "float32", fp8=control)

@@ -18,7 +18,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from benchlib import inputs, reference, weights
+from benchlib import backend, inputs, reference, weights
 
 N_TT = len(inputs.TASK_TYPES)
 N_DM = len(inputs.DOMAINS)
@@ -36,7 +36,7 @@ class System:
     analyzer_params: Dict                # numpy, bench-made
     backend: Optional[dict] = None       # served model dims (config)
     backend_name: str = ""
-    backend_weights: Optional[Dict] = None   # stacked, bench-made
+    backend_weights: Optional[Dict] = None   # bench-made
     runner: object = None                # ModelRunner of the backend
     on_chip: set = field(default_factory=set)
 
@@ -90,19 +90,6 @@ def _register(mres, names, raw, raw01, tt_m, dm_m, gen, runners):
         for i in range(len(names))])
 
 
-def model_config(b: dict):
-    """The program's ``ModelConfig`` for a backend configuration."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=b["model"], arch_type=b["architecture"],
-        n_layers=b["num_hidden_layers"], d_model=b["hidden_size"],
-        n_heads=b["num_attention_heads"], n_kv_heads=b["num_key_value_heads"],
-        d_ff=b["intermediate_size"], vocab_size=b["vocab_size"],
-        qkv_bias=b["qkv_bias"], rope_theta=float(b["rope_theta"]),
-        param_dtype=b["param_dtype"], compute_dtype=b["compute_dtype"],
-        source=b["source"]).validate()
-
-
 def build(cfg: dict, seed: int, cache_dir: pathlib.Path) -> System:
     from repro.core.analyzer import AnalyzerConfig, TaskAnalyzer
     from repro.core.mres import MRES
@@ -110,6 +97,8 @@ def build(cfg: dict, seed: int, cache_dir: pathlib.Path) -> System:
     from repro.serving.engine import ServingEngine
     from repro.serving.runner import ModelRunner
 
+    b = cfg.get("backend")
+    arch = backend.of(b) if b is not None else None
     a = cfg["analyzer"]
     an_params = weights.analyzer_weights(a, cache_dir)
     analyzer = TaskAnalyzer(AnalyzerConfig(
@@ -120,12 +109,11 @@ def build(cfg: dict, seed: int, cache_dir: pathlib.Path) -> System:
     analyzer.params = jax.tree_util.tree_map(jax.numpy.asarray, an_params)
 
     names, raw, tt_m, dm_m, gen, raw01, backends = _catalog_rows(cfg, seed)
-    b = cfg.get("backend")
     runner = bw = None
-    if b is not None:
-        mc = model_config(b)
-        bw = weights.decoder_weights(b, seed, mc.vocab_padded)
-        runner = ModelRunner(mc, params=weights.program_params(bw))
+    if arch is not None:
+        mc = arch.program_config(b)
+        bw = arch.make_weights(b, seed, mc.vocab_padded)
+        runner = ModelRunner(mc, params=arch.program_params(bw))
     runners = [runner if be is not None else None for be in backends]
     mres = MRES()
     _register(mres, names, raw, raw01, tt_m, dm_m, gen, runners)

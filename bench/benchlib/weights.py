@@ -4,10 +4,9 @@
   from the configuration's fixed seed and step count on the frozen
   workload generator (plain Adam on the heads' cross-entropies and the
   complexity error), then kept under ``.bench_cache/`` in the checkout.
-* ``decoder_weights``: the served backend's weights at its published
-  widths, random from ``--seed``, made on the device in one jitted call
-  in the dtype they are served in, stacked over layers; the program
-  gets them as its parameter tree without a copy.
+
+A served backend's weights are made by its module's ``make_weights``
+(``bench/backends/<architecture>.py``).
 """
 from __future__ import annotations
 
@@ -128,56 +127,3 @@ def analyzer_weights(a: dict, cache_dir: pathlib.Path) -> Dict:
     np.savez(tmp, **_flatten(params))
     tmp.replace(path)
     return params
-
-
-# ----------------------------------------------------------------------
-# served decoder
-# ----------------------------------------------------------------------
-
-def decoder_weights(m: dict, seed: int, vocab_rows: int):
-    """Random decoder weights for ``seed``, in fp32 (the configured
-    parameter dtype), stacked over layers: one jitted call on the
-    device.  ``vocab_rows`` is the embedding's row count as the program
-    lays it out (the vocabulary padded up)."""
-    d, L = m["hidden_size"], m["num_hidden_layers"]
-    hd = d // m["num_attention_heads"]
-    q, kv, f = m["num_attention_heads"] * hd, m["num_key_value_heads"] * hd, \
-        m["intermediate_size"]
-
-    def make(key):
-        ks = jax.random.split(key, 9)
-
-        def w(k, shape, fan_in):
-            return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
-
-        def small(k, shape):
-            return jax.random.normal(k, shape, jnp.float32) * 0.02
-
-        return {
-            "embed": small(ks[0], (vocab_rows, d)),
-            "ln_f": jnp.zeros((d,), jnp.float32),
-            "wq": w(ks[1], (L, d, q), d), "wk": w(ks[2], (L, d, kv), d),
-            "wv": w(ks[3], (L, d, kv), d), "wo": w(ks[4], (L, q, d), q),
-            "bq": small(ks[5], (L, q)), "bk": small(ks[6], (L, kv)),
-            "bv": small(ks[7], (L, kv)),
-            "wg": w(ks[8], (L, d, f), d),
-            "wi": w(jax.random.fold_in(ks[8], 1), (L, d, f), d),
-            "wo_mlp": w(jax.random.fold_in(ks[8], 2), (L, f, d), f),
-            "ln_mix": jnp.zeros((L, d), jnp.float32),
-            "ln_mlp": jnp.zeros((L, d), jnp.float32),
-        }
-
-    key = jax.random.fold_in(jax.random.PRNGKey(seed % 2 ** 32),
-                             seed // 2 ** 32)
-    return jax.jit(make)(key)
-
-
-def program_params(w: Dict) -> Dict:
-    """The weights as the program's parameter tree (no copy)."""
-    return {"embed": w["embed"], "ln_f": w["ln_f"],
-            "layers": {"ln_mix": w["ln_mix"], "ln_mlp": w["ln_mlp"],
-                       "attn": {"wq": w["wq"], "wk": w["wk"], "wv": w["wv"],
-                                "wo": w["wo"], "bq": w["bq"], "bk": w["bk"],
-                                "bv": w["bv"]},
-                       "mlp": {"wg": w["wg"], "wi": w["wi"],
-                               "wo": w["wo_mlp"]}}}

@@ -3,8 +3,9 @@
   python bench/calibrate.py --workload <cell> --seeds 1,2,... \
       [--control-seeds 1,2,3] [--seconds 10] [--out FILE]
 
-For each seed, in one process: build the cell from the seed, warm up,
-run a short window at the cell's own load, and read every number that
+For each seed, in one process: build the cell from the seed, warm up
+(the first seed only: the later ones find every shape compiled), run a
+short window at the cell's own load, and read every number that
 ``correct`` compares for the program.  For the control seeds, read the
 same numbers for the control as well, through the same comparison that
 decides ``correct`` (``check.check_run(control=True)``): the reference
@@ -33,9 +34,9 @@ def _ints(s):
     return [int(x) for x in s.split(",") if x]
 
 
-def readings(cell, seed, seconds, control, cache_dir=None):
+def readings(cell, seed, seconds, control, cache_dir=None, warm=True):
     from benchlib import check, drive, harness
-    sysm, tr = harness.prepare(cell, seed, seconds, cache_dir)
+    sysm, tr = harness.prepare(cell, seed, seconds, cache_dir, warm)
     run = drive.run(sysm, tr, seconds)
     res = harness.outcomes(sysm, run)
     out = {"seed": seed, "attempted": res["attempted"],
@@ -67,10 +68,12 @@ def main(argv=None) -> int:
     harness.enable_cache()
     sink = open(args.out, "a") if args.out else None
     try:
-        for seed in args.seeds:
+        for i, seed in enumerate(args.seeds):
             t = time.perf_counter()
+            # the first seed warms every shape up; the later ones reuse
+            # them (their windows are not timed)
             line = readings(cell, seed, args.seconds,
-                            seed in args.control_seeds)
+                            seed in args.control_seeds, warm=i == 0)
             line["wall_s"] = time.perf_counter() - t
             text = json.dumps(line)
             print(text, flush=True)

@@ -1,6 +1,7 @@
-"""Kernel: least time of the decode steps (bf16 weights plus the keys
-and values attended, ``counts.decode_step``) over their device time, in
-percent, summed over every traced ``generate`` call."""
+"""Kernel: least time of the decode steps (the backend's
+``decode_step``: its weights in bf16 plus the keys and values attended)
+over their device time, in percent, summed over every traced
+``generate`` call."""
 from benchlib import readers
 
 

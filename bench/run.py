@@ -13,7 +13,8 @@ report compilations inside the window, how late the load generator
 ran and the share of requests served on the chip.
 
 Exits non-zero with no result line when JAX sees no TPU, fewer chips
-than the cell asks for, or a device that the peak table lacks.
+than the cell asks for, a device that the peak table lacks, or a
+backend architecture with no module under ``bench/backends/``.
 """
 import time
 
@@ -45,11 +46,12 @@ def main(argv=None) -> int:
     # machine share nothing
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     sys.path[:0] = [str(ROOT / "src"), str(BENCH)]
-    from benchlib import harness, peaks
+    from benchlib import backend, harness, peaks
     try:
         cell = harness.load_cell(args.workload, ROOT)
         harness.device_info(cell.chips)
-    except (harness.NoChip, peaks.UnknownDevice, KeyError) as e:
+    except (harness.NoChip, peaks.UnknownDevice, backend.UnknownArchitecture,
+            KeyError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     # any whole number is a seed; negative ones fold into the same range

@@ -63,7 +63,12 @@ def tiny_cell(name):
                               intermediate_size=128, vocab_size=512)
         cfg["engine"]["prompt_len"] = 32
     mix = copy.deepcopy(c.mix)
-    mix["max_new"] = min(mix["max_new"], 8)
+    if "max_new" in mix:
+        mix["max_new"] = min(mix["max_new"], 8)
+    if "answers" in mix:
+        mix["answers"].update(median=4, buckets=[2, 4, 8])
+    if "prompt_tokens" in mix["texts"]:
+        mix["texts"]["prompt_tokens"]["median"] = 30
     if "arrivals" in mix:
         mix["arrivals"]["rps"] = 10.0
     if "max_batch" in mix:
@@ -76,8 +81,22 @@ def tiny_cell(name):
     return c
 
 
-def _run(name, seed, tamper=None, seconds=2.0, trace=False, cache=None):
-    return harness.run_cell(tiny_cell(name), seed, seconds, trace,
+def _run(name, seed, tamper=None, seconds=2.0, trace=False, cache=None,
+         load="light"):
+    """``load``: the tiny cell's light load; ``full_windows``, windows
+    that fill as the chip's do, where a generate takes seconds (groups
+    of several requests in one answer bucket); ``own``, the mix's own
+    rate and window (the tiny cell's batch of 4, which the groups formed
+    there do not fill)."""
+    cell = tiny_cell(name)
+    if load == "full_windows":
+        cell.mix.update(max_wait_ms=200)
+        cell.mix["arrivals"]["rps"] = 20.0
+    elif load == "own":
+        own = _cell(name).mix
+        cell.mix.update(max_wait_ms=own["max_wait_ms"])
+        cell.mix["arrivals"]["rps"] = own["arrivals"]["rps"]
+    return harness.run_cell(cell, seed, seconds, trace,
                             t_process=time.perf_counter(), require_tpu=False,
                             cache_dir=cache, tamper=tamper)
 
@@ -176,10 +195,50 @@ def _half_batch(sysm):
     sysm.runner.generate = half
 
 
-@pytest.mark.parametrize("fault", [_stale_cache, _half_batch],
-                         ids=["stale_cache", "half_batch"])
-def test_served_fault_is_caught(fault, cache):
-    res = _run("served-qwen2-open", 13, cache=cache, tamper=fault)
+# seconds a generate of the tiny backend takes per answer token: the
+# chip's qwen2-1.5b takes about 5 ms a decode step for answers 64 times
+# as long, so a generate lasts about as long as there
+CHIP_LIKE_S_PER_TOKEN = 0.32
+
+
+def _chip_like(fault):
+    """``fault`` behind a backend slowed to the chip's time a generate,
+    so that at the mix's own load requests queue behind the generate in
+    progress as they do there."""
+    def plant(sysm):
+        fault(sysm)
+        inner = sysm.runner.generate
+
+        def slow(tokens, max_new=16):
+            time.sleep(CHIP_LIKE_S_PER_TOKEN * max_new)
+            return inner(tokens, max_new=max_new)
+
+        sysm.runner.generate = slow
+    return plant
+
+
+@pytest.mark.parametrize("fault,load,seconds", [
+    (_stale_cache, "full_windows", 2.0),
+    (_half_batch, "full_windows", 2.0),
+    (_chip_like(_half_batch), "own", 6.0)],
+    ids=["stale_cache", "half_batch", "half_batch_at_own_load"])
+def test_served_fault_is_caught(fault, load, seconds, cache):
+    groups = []
+
+    def plant(sysm):
+        fault(sysm)
+        inner = sysm.runner.generate
+
+        def count(tokens, max_new=16):
+            groups.append(len(tokens))
+            return inner(tokens, max_new=max_new)
+
+        sysm.runner.generate = count
+
+    res = _run("served-qwen2-open", 13, cache=cache, tamper=plant,
+               seconds=seconds, load=load)
+    # the backend was handed groups of several requests
+    assert max(groups) >= 2
     assert not res["correct"]
     assert res["checks"]["logit_gap_mean"]["value"] > \
         res["checks"]["logit_gap_mean"]["limit"]
@@ -231,7 +290,8 @@ def test_trace_is_written_only_once_the_run_asks(tmp_path):
 def test_request_median_takes_on_chip_answers_and_failures():
     """``request_p50_ms`` is the median, from each request's due time,
     over the requests the on-chip backend answered with tokens and the
-    failed ones; answers from models off the chip do not count."""
+    failed ones; answers from models off the chip do not count there,
+    and make up ``decide_p50_ms`` with the failed ones."""
     from types import SimpleNamespace as NS
     from benchlib import drive
 
@@ -253,8 +313,10 @@ def test_request_median_takes_on_chip_answers_and_failures():
     assert res["on_chip_requests"] == 5 and res["failed"] == 1
     assert res["request_p50_ms"] == pytest.approx(np.percentile(waits, 50))
     assert res["request_p95_ms"] == pytest.approx(np.percentile(waits, 95))
+    # the decision's median: the requests that end at the decision, with
+    # the failed one
     assert res["decide_p50_ms"] == pytest.approx(np.percentile(
-        np.r_[waits, np.array([0.01, 0.02, 0.015, 0.03]) * 1e3], 50))
+        np.r_[np.array([0.01, 0.02, 0.015, 0.03]) * 1e3, 7.0e3], 50))
 
 
 def test_traced_served_run_reads_its_host_metrics(cache):
@@ -349,3 +411,81 @@ def test_closed_loops_run_correct(base, mix, cache):
                            cache_dir=cache)
     assert res["correct"], res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
+
+
+def test_unknown_architecture_fails_at_set_up(tmp_path):
+    """A configuration whose backend names an architecture with no
+    module fails when its cell is loaded, naming the path looked for."""
+    from benchlib import backend
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cfg = json.loads((_BENCH / "configs" / "tier4-qwen2.json").read_text())
+    cfg["backend"]["architecture"] = "no-such-architecture"
+    (tmp_path / "cfg.json").write_text(json.dumps(cfg))
+    spec["configs"].append({"name": "tier4-qwen2", "file": "cfg.json"})
+    spec["workloads"].append({"name": "served-qwen2-open",
+                              "config": "tier4-qwen2",
+                              "traffic": "chat-open", "chips": 1})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    with pytest.raises(backend.UnknownArchitecture) as e:
+        harness.load_cell("served-qwen2-open", tmp_path)
+    assert str(backend.DIR / "no-such-architecture.py") in str(e.value)
+
+
+def test_new_backend_is_taken_from_a_new_file(tmp_path, cache, monkeypatch):
+    """A backend module written as one new file under the backends
+    directory serves a cell whose configuration names it: the harness,
+    the reference and the readers reach it with no other file edited."""
+    from benchlib import backend
+    d = tmp_path / "backends"
+    d.mkdir()
+    (d / "toy.py").write_text((backend.DIR / "dense.py").read_text() + '''
+CALLS = []
+_dense_logits = logits
+
+
+def logits(w, b, tokens, *, control=False):
+    CALLS.append(len(tokens))
+    return _dense_logits(w, b, tokens, control=control)
+''')
+    cell = tiny_cell("served-qwen2-open")
+    cell.config["backend"]["architecture"] = "toy"
+    monkeypatch.setattr(backend, "DIR", d)
+    res = harness.run_cell(cell, 14, 2.0, False,
+                           t_process=time.perf_counter(), require_tpu=False,
+                           cache_dir=cache)
+    assert res["correct"], res["checks"]
+    toy = backend.load("toy")
+    assert toy.__file__ == str(d / "toy.py")
+    assert toy.CALLS and "logit_gap_mean" in res["checks"]
+
+
+def _waits_ms(recs, t1):
+    return [((r.done if r.done is not None else t1 + 60.0) - r.due) * 1e3
+            for r in recs]
+
+
+def test_decide_median_is_over_requests_that_end_at_the_decision(cache):
+    """``decide_p50_ms`` is the median over the requests that end at the
+    decision.  On a run of decide-1m-open, where every request does, it
+    is the median over all of them, as it was read before the split; on
+    the served cell it takes the off-chip answers and leaves the on-chip
+    ones to ``request_p50_ms``."""
+    from benchlib import drive
+    for name in CELLS:
+        sysm, tr = harness.prepare(tiny_cell(name), 15, 2.0, cache)
+        run = drive.run(sysm, tr, 2.0)
+        res = harness.outcomes(sysm, run)
+        chip = [r for r in run.records
+                if getattr(r.answer, "model", None) in sysm.on_chip]
+        off = [r for r in run.records if r not in chip]
+        assert res["failed"] == 0
+        assert res["decide_p50_ms"] == pytest.approx(
+            np.percentile(_waits_ms(off, run.t1), 50), rel=1e-12)
+        if not sysm.on_chip:
+            assert not chip
+            assert res["decide_p50_ms"] == pytest.approx(np.percentile(
+                _waits_ms(run.records, run.t1), 50), rel=1e-12)
+        else:
+            assert chip and off
+            assert res["request_p50_ms"] == pytest.approx(
+                np.percentile(_waits_ms(chip, run.t1), 50), rel=1e-12)

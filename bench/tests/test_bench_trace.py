@@ -12,7 +12,7 @@ import pytest
 _BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(_BENCH), str(_BENCH.parent / "src")]
 
-from benchlib import counts, harness, peaks, readers, trace  # noqa: E402
+from benchlib import backend, harness, peaks, readers, trace  # noqa: E402
 from benchlib.trace import Device, Event, Reduced  # noqa: E402
 
 RECORDED = _BENCH / "tests" / "data" / "small_v5e.xplane.pb"
@@ -156,7 +156,7 @@ def _served_trace():
 def test_prefill_and_decode_readers():
     mods, spans, calls = _served_trace()
     red = _reduced(mods, spans, 40 * MS)
-    m = {"hidden_size": 64, "num_hidden_layers": 2,
+    m = {"architecture": "dense", "hidden_size": 64, "num_hidden_layers": 2,
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "intermediate_size": 128, "vocab_size": 512, "qkv_bias": True}
     ctx = _ctx(red, system=SimpleNamespace(backend=m))
@@ -166,7 +166,7 @@ def test_prefill_and_decode_readers():
     least = sum(readers.decode_least_s(ctx, B, 16, 2) for B in (2, 4))
     assert harness.load_reader("decode_roofline")(ctx) == pytest.approx(
         100 * least / 8e-3)
-    flops = sum(counts.decode_step(m, B, 16 + j + 1).flops
+    flops = sum(backend.of(m).decode_step(m, B, 16 + j + 1).flops
                 for B in (2, 4) for j in range(2))
     assert harness.load_reader("decode_mfu")(ctx) == pytest.approx(
         100 * flops / 8e-3 / ctx.peak.bf16_flops)
