@@ -64,14 +64,20 @@ def test_merge_topk_with_neginf_padding():
 # ----------------------------------------------------------------------
 
 def _random_problem(B, N, seed, *, with_fb=True, with_ad=True,
-                    with_load=True):
+                    with_load=True, tt_p=0.4, dm_p=0.5, period=0):
+    """A random routing problem; ``period`` > 0 repeats the first
+    ``period`` catalog rows (embedding and filter columns) over the
+    whole catalog, so equal scores straddle every top-k chunk."""
     rng = np.random.default_rng(seed)
     M = len(METRICS)
     nt, nd = len(TASK_TYPES), len(DOMAINS)
     emb = rng.random((N, M)).astype(np.float32)
-    tt = np.vstack([rng.random((nt, N)) < 0.4, np.ones((1, N), bool)])
-    dm = np.vstack([rng.random((nd, N)) < 0.5, np.ones((1, N), bool)])
+    tt = np.vstack([rng.random((nt, N)) < tt_p, np.ones((1, N), bool)])
+    dm = np.vstack([rng.random((nd, N)) < dm_p, np.ones((1, N), bool)])
     gmask = rng.random(N) < 0.2
+    if period:
+        rep = np.arange(N) % period
+        emb, tt, dm, gmask = emb[rep], tt[:, rep], dm[:, rep], gmask[rep]
     T = rng.random((B, M)).astype(np.float32)
     W = rng.random((B, M)).astype(np.float32)
     ti = rng.integers(0, nt + 1, B).astype(np.int32)
@@ -98,13 +104,33 @@ def _ref_kwargs(kw):
             for k2, v in kw.items()}
 
 
-@pytest.mark.parametrize("B,N,k,flags", [
-    (1, 5, 3, (True, True, True)),      # B=1, tiny catalog
-    (9, 130, 8, (True, False, True)),   # N just past one lane block
-    (16, 257, 4, (False, True, False)),  # off-by-one catalog
-    (33, 96, 2, (False, False, False)),  # blend-free, non-pow2 batch
-])
-def test_route_step_matches_ref(B, N, k, flags):
+def _forms(cases):
+    """``(id, args)`` cases for a test taking a trailing ``select``:
+    each in the CPU select form under its own id, then in the TPU form
+    as ``tpu-<id>``."""
+    return ([pytest.param(*a, "cpu", id=i) for i, a in cases]
+            + [pytest.param(*a, "tpu", id=f"tpu-{i}") for i, a in cases])
+
+
+def _force_select(monkeypatch, select):
+    """Run the dense program's select stage in ``select``'s form, which
+    ``ops.select_form`` otherwise reads from the catalog's platform."""
+    monkeypatch.setattr(K, "select_form", lambda x: select)
+
+
+@pytest.mark.parametrize("B,N,k,flags,select", _forms([
+    ("1-5-3-flags0", (1, 5, 3, (True, True, True))),  # B=1, tiny catalog
+    ("9-130-8-flags1", (9, 130, 8, (True, False, True))),  # N past a lane block
+    ("16-257-4-flags2", (16, 257, 4, (False, True, False))),  # off-by-one N
+    ("33-96-2-flags3", (33, 96, 2, (False, False, False))),  # non-pow2 batch
+    # 12 lane tiles > R, so both forms' chunked top-k runs, with 36
+    # pad columns in the last tile; Qp 8 and Qp 32
+    ("5-1500-8-flags0", (5, 1500, 8, (True, True, True))),
+    ("20-1500-8-flags0", (20, 1500, 8, (True, True, True))),
+    ("20-1500-8-flags3", (20, 1500, 8, (False, False, False))),
+]))
+def test_route_step_matches_ref(monkeypatch, B, N, k, flags, select):
+    _force_select(monkeypatch, select)
     args, kw = _random_problem(B, N, seed=B * 1000 + N,
                                with_fb=flags[0], with_ad=flags[1],
                                with_load=flags[2])
@@ -119,6 +145,57 @@ def test_route_step_matches_ref(B, N, k, flags):
     for key in ("score", "similarity", "cand_score"):
         np.testing.assert_allclose(got[key], np.asarray(want[key]),
                                    rtol=2e-5, atol=2e-5, err_msg=key)
+
+
+@pytest.mark.parametrize("B,kind,quant", [
+    (5, "ties", False), (20, "ties", False),
+    (5, "fallback", False), (20, "fallback", True),
+    (5, "extras", True), (20, "extras", True)])
+def test_tpu_select_form_matches_cpu_form_and_ref(monkeypatch, B, kind,
+                                                  quant):
+    """The TPU select form gives the CPU form's values bit for bit, and
+    the oracle's.  ``ties``: every row repeats with period 7, so equal
+    values straddle each chunk boundary; the picked indices may then
+    differ (the contract ``_hier_topk`` documents), but each names a
+    row identical to the oracle's pick.  ``fallback``: sparse filters,
+    so most rows walk the ladder.  ``extras``: feedback, bandit and
+    load terms on."""
+    N, k = 1500, 8
+    args, kw = _random_problem(
+        B, N, seed=B + 31, with_fb=kind == "extras",
+        with_ad=kind == "extras", with_load=kind == "extras",
+        tt_p=0.002 if kind == "fallback" else 0.4,
+        dm_p=0.002 if kind == "fallback" else 0.5,
+        period=7 if kind == "ties" else 0)
+    got = {}
+    for select in ("cpu", "tpu"):
+        _force_select(monkeypatch, select)
+        got[select] = K.route_step(*args, k=k, r=k, quant=quant, **kw)
+    want = R.route_step(*(jnp.asarray(a) for a in args), k, k,
+                        quant=quant, **_ref_kwargs(kw))
+    cpu, tpu = got["cpu"], got["tpu"]
+    if kind == "fallback":
+        assert (tpu["stage"] > 0).mean() >= 0.6
+    for key in ("stage", "n_filtered", "n_candidates", "score",
+                "similarity", "cand_score"):
+        np.testing.assert_array_equal(tpu[key], cpu[key], err_msg=key)
+    for key in ("stage", "n_filtered", "n_candidates"):
+        np.testing.assert_array_equal(tpu[key], np.asarray(want[key]),
+                                      err_msg=key)
+    for key in ("score", "similarity", "cand_score"):
+        np.testing.assert_allclose(tpu[key], np.asarray(want[key]),
+                                   rtol=2e-5, atol=2e-5, err_msg=key)
+    if kind == "ties":
+        emb, ci = args[0], np.asarray(want["cand_idx"])
+        live = tpu["cand_idx"] >= 0
+        np.testing.assert_array_equal(live, ci >= 0)
+        np.testing.assert_array_equal(emb[tpu["cand_idx"][live]],
+                                      emb[ci[live]])
+    else:
+        for key in ("model_idx", "cand_idx"):
+            np.testing.assert_array_equal(tpu[key], cpu[key])
+            np.testing.assert_array_equal(tpu[key],
+                                          np.asarray(want[key]))
 
 
 def test_route_step_pallas_path_matches_jnp():
@@ -183,11 +260,18 @@ def _full_engine(n=64, seed=0, *, with_fb=True, with_ad=True,
                          load=load, load_weight=0.5 if load else 0.0)
 
 
-@pytest.mark.parametrize("b", [1, 13])
-@pytest.mark.parametrize("flags", [(False, False, False),
-                                   (True, True, True)])
-def test_fused_matches_staged_full_blend(b, flags):
-    eng = _full_engine(64, seed=b, with_fb=flags[0], with_ad=flags[1],
+_FLAGS = [(False, False, False), (True, True, True)]
+
+
+@pytest.mark.parametrize("b,flags,n,select", _forms(
+    [(f"flags{i}-{b}", (b, f, 64)) for i, f in enumerate(_FLAGS)
+     for b in (1, 13)]
+    # 12 lane tiles: the chunked top-k runs; Qp 8 and Qp 32
+    + [(f"flags{i}-{b}-n1500", (b, f, 1500)) for i, f in enumerate(_FLAGS)
+       for b in (5, 20)]))
+def test_fused_matches_staged_full_blend(monkeypatch, b, flags, n, select):
+    _force_select(monkeypatch, select)
+    eng = _full_engine(n, seed=b, with_fb=flags[0], with_ad=flags[1],
                        with_load=flags[2])
     prefs, sigs = random_queries(b, seed=b + 5)
     fused = eng.route_many_batch(prefs, sigs).decisions()
@@ -197,6 +281,15 @@ def test_fused_matches_staged_full_blend(b, flags):
 
 def test_fused_matches_staged_fallback_heavy():
     """A catalog with narrow coverage forces every fallback rung."""
+    _check_fallback_heavy()
+
+
+def test_fused_matches_staged_fallback_heavy_tpu_form(monkeypatch):
+    _force_select(monkeypatch, "tpu")
+    _check_fallback_heavy()
+
+
+def _check_fallback_heavy():
     from tests.conftest import make_entry
     from repro.core.mres import MRES
     m = MRES()

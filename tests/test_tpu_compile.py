@@ -105,7 +105,7 @@ def test_route_step_compiles(one_chip, use_pallas, quant):
         o["theta"], o["ainv_flat"], o["lpen"], s((3,), jnp.float32),
         k=8, r=8, n_tt=N_TT, n_dm=N_DM, has_fb=False, has_ad=False,
         has_load=False, use_pallas=use_pallas, blk_q=8, blk_n=128,
-        interpret=False, quant=quant).compile()
+        interpret=False, quant=quant, select="tpu").compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
 
 
@@ -129,6 +129,39 @@ def test_analyze_route_step_compiles_with_kernel(one_chip, quant):
         use_pallas=True, blk_q=8, blk_n=128, interpret=False,
         quant=quant).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decision_program_select_stage_relays_out_nothing(one_chip):
+    """decide-1m-open's program (Qp 8, a 1,000,064-column catalog,
+    fp32, no Pallas) with the TPU select form: no ``while`` in the
+    entry computation, and at most 0.6 GB accessed by XLA's estimate.
+    The chunk-of-32 form reads 1.64 GB through two ``while`` copies of
+    the scores, a gather of the whole mask table and a row-major copy
+    of the catalog block."""
+    from repro.core.analyzer import AnalyzerConfig, init_analyzer
+    from repro.kernels.analyze_step import analyze_route_step_jit
+    cfg = AnalyzerConfig()
+    qp, n_cat = 8, 1_000_064
+    s = lambda *a: _sds(one_chip, *a)  # noqa: E731
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_analyzer(jax.random.PRNGKey(0), cfg)))
+    rows = N_TT * N_DM + N_TT + 2
+    compiled = analyze_route_step_jit.lower(
+        params, s((qp, cfg.max_len), jnp.int32), s((qp, M), jnp.float32),
+        s((1,), jnp.float32), s((1, 1), jnp.float32),
+        s((n_cat, 2 * M), jnp.float32), s((1, 2), jnp.float32),
+        s((rows, n_cat), jnp.bool_), s((rows,), jnp.int32),
+        s((1, 1), jnp.float32), s((1, 1), jnp.float32),
+        s((1,), jnp.float32), s((3,), jnp.float32), cfg=cfg, acc_col=0,
+        use_complexity=True, fb_buckets=4, k=8, r=8, n_tt=N_TT,
+        n_dm=N_DM, has_fb=False, has_ad=False, has_load=False,
+        use_pallas=False, blk_q=8, blk_n=128, interpret=False,
+        quant=False, select="tpu").compile()
+    text = compiled.as_text()
+    assert "while(" not in text[text.index("ENTRY"):]
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= 0.6e9, cost["bytes accessed"]
 
 
 def test_bandit_update_compiles(one_chip):
