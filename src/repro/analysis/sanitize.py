@@ -180,7 +180,7 @@ class RecompileSentinel:
     def __call__(self, event: dict) -> None:
         sig = (event.get("path"), event.get("q_bucket"),
                event.get("n_bucket"), event.get("quant"),
-               event.get("shards"), event.get("select"))
+               event.get("shards"))
         compiles = int(event.get("compiles", 0) or 0)
         with self._mu:
             # any prior dispatch of this signature — compiled or served

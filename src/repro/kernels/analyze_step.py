@@ -118,7 +118,7 @@ def analyze_step_jit(params, tokens, *, cfg):
     static_argnames=("cfg", "acc_col", "use_complexity", "fb_buckets",
                      "k", "r", "n_tt", "n_dm", "has_fb", "has_ad",
                      "has_load", "use_pallas", "blk_q", "blk_n",
-                     "interpret", "quant", "select"))
+                     "interpret", "quant"))
 def analyze_route_step_jit(params, tokens, W, ascalars, fb_table,
                            e2, e2s, masks_table, counts_table,
                            theta, ainv_flat, lpen, rparams, *,
@@ -127,8 +127,7 @@ def analyze_route_step_jit(params, tokens, W, ascalars, fb_table,
                            n_tt: int, n_dm: int, has_fb: bool,
                            has_ad: bool, has_load: bool,
                            use_pallas: bool, blk_q: int, blk_n: int,
-                           interpret: bool, quant: bool = False,
-                           select: str = "cpu"):
+                           interpret: bool, quant: bool = False):
     """ONE program from token ids to model choice.
 
     tokens (Qp, L) int32 bucket-padded queries; W (Qp, M) preference
@@ -167,7 +166,7 @@ def analyze_route_step_jit(params, tokens, W, ascalars, fb_table,
         theta, ainv_flat, lpen, rparams, k=k, r=r, n_tt=n_tt,
         n_dm=n_dm, has_fb=has_fb, has_ad=has_ad, has_load=has_load,
         use_pallas=use_pallas, blk_q=blk_q, blk_n=blk_n,
-        interpret=interpret, quant=quant, select=select)
+        interpret=interpret, quant=quant)
     out.update(tt_idx=tt_idx, dm_idx=dm_idx, cx=cx, conf=conf,
                task_vectors=T)
     return out

@@ -20,13 +20,14 @@ from repro.kernels.analyze_step import (analyze_route_step_jit,
 from repro.kernels.bandit_update import bandit_update_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gating import moe_gating_pallas
-from repro.kernels.route_step import (LANE, route_step_ivf_jit,
-                                      route_step_jit,
+from repro.kernels.route_step import (route_step_ivf_jit, route_step_jit,
                                       route_step_sharded_jit)
 from repro.kernels.router_topk import (Q8_BLK_Q, router_topk_pallas,
                                        router_topk_q8_pallas)
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.obs.trace import span
+
+LANE = 128
 
 
 def default_interpret() -> bool:
@@ -142,10 +143,8 @@ def set_recompile_hook(hook) -> None:
     """Attach (or detach with ``None``) a per-dispatch recompile hook.
 
     The hook is called as ``hook(event)`` with ``event = {"path",
-    "q_bucket", "n_bucket", "quant", "shards", "select", "compiles"}``
-    (``select``: the dense program's select form, None where no such
-    stage runs) after every ``route_step`` dispatch, and likewise after
-    every
+    "q_bucket", "n_bucket", "quant", "shards", "compiles"}`` after
+    every ``route_step`` dispatch, and likewise after every
     ``analyze_step`` dispatch (``path="analyze"``, ``n_bucket`` = the
     token axis, ``quant`` = analyzer int8) and every fused
     ``analyze_route_step`` dispatch (``path="fused"``, ``quant`` =
@@ -167,13 +166,6 @@ def _dummies():
         _DUMMIES = (jnp.zeros((1, 1), jnp.float32),
                     jnp.zeros((1,), jnp.float32))
     return _DUMMIES
-
-
-def select_form(x) -> str:
-    """The dense program's select-stage form (``kernels/route_step.py``)
-    for the platform the catalog pack ``x`` lives on: "tpu" on a TPU,
-    "cpu" elsewhere."""
-    return "tpu" if next(iter(x.devices())).platform == "tpu" else "cpu"
 
 
 def _count_compiles(jit_fn, call):
@@ -513,9 +505,8 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
     other's deltas out of the shared counters.  The dispatch runs in a
     ``route_step`` span (``obs.trace.span``: in the profiler's timeline,
     and in ``tracer``'s ring when one is given, carrying the selected
-    path, shape bucket, quantization mode, shard count, the dense
-    program's select form (``select_form``) and compile delta); an
-    attached cost profiler (see
+    path, shape bucket, quantization mode, shard count and compile
+    delta); an attached cost profiler (see
     ``set_cost_profiler``) gets each NEW shape bucket's bound call to
     read ``compiled.cost_analysis()`` from.
 
@@ -593,7 +584,6 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
             cap=cap, has_fb=has_fb, has_ad=has_ad,
             has_load=has_load, quant=quant)
         path, n_pad, shards = "ivf", cap, 1
-        select = None
     elif mesh is not None:
         from repro.sharding.rules import CATALOG_AXIS
         ndev = mesh.shape[CATALOG_AXIS]
@@ -620,14 +610,12 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
             has_fb=has_fb, has_ad=has_ad, has_load=has_load,
             quant=quant)
         path, n_pad, shards = "sharded", np_pad, ndev
-        select = None
     else:
         np_pad = n_bucket(n)
         npad = np_pad - n
         blk_n = 512 if np_pad % 512 == 0 else LANE
         e2_d, e2s_d, masks_d, counts_d = _catalog_pack(
             emb, tt_matrix, dm_matrix, gmask, np_pad, quant=quant)
-        select = select_form(e2_d)
         fbp = np.pad(np.asarray(fb, np.float32),
                      ((0, qpad), (0, npad))) if has_fb else dummy1[0]
         if has_ad:
@@ -644,22 +632,21 @@ def route_step(emb, tt_matrix, dm_matrix, gmask, T, W, ti, di, *,
             thp, aip, lpp, params, k=k, r=r, n_tt=n_tt, n_dm=n_dm,
             has_fb=has_fb, has_ad=has_ad, has_load=has_load,
             use_pallas=use_pallas, blk_q=8, blk_n=blk_n,
-            interpret=interp, quant=quant, select=select)
+            interpret=interp, quant=quant)
         path, n_pad, shards = "dense", np_pad, 1
     prof = _COST_PROFILER
     if prof is not None:
         prof.capture((path, qp, n_pad, quant, shards), jit_fn, call)
     with span(tracer, "route_step", path=path, batch=B, q_bucket=qp,
-              n_bucket=n_pad, catalog_n=n, quant=quant, shards=shards,
-              select=select) as sp:
+              n_bucket=n_pad, catalog_n=n, quant=quant,
+              shards=shards) as sp:
         out, compiles = _count_compiles(jit_fn, call)
         sp.set(compiles=compiles)
     _bump("route_step", compiles)
     hook = _RECOMPILE_HOOK
     if hook is not None:
         hook({"path": path, "q_bucket": qp, "n_bucket": n_pad,
-              "quant": quant, "shards": shards, "select": select,
-              "compiles": compiles})
+              "quant": quant, "shards": shards, "compiles": compiles})
     if telemetry is not None:
         telemetry.record_route_step(dispatches=1, compiles=compiles)
     out = jax.device_get(out)           # ONE host transfer for all
@@ -725,8 +712,7 @@ def analyze_step(params, cfg, tokens, *, telemetry=None,
     hook = _RECOMPILE_HOOK
     if hook is not None:
         hook({"path": "analyze", "q_bucket": qp, "n_bucket": L,
-              "quant": quant, "shards": 1, "select": None,
-              "compiles": compiles})
+              "quant": quant, "shards": 1, "compiles": compiles})
     if telemetry is not None:
         telemetry.record_analyze_step(dispatches=1, compiles=compiles)
     out = jax.device_get(out)           # ONE host transfer for all
@@ -762,8 +748,7 @@ def analyze_route_step(params, cfg, tokens, emb, tt_matrix, dm_matrix,
     One dispatch feeds BOTH counter families (``route_step_*`` and
     ``analyze_step_*``), one ``path="fused"`` hook event whose
     ``quant`` field is ``(catalog_int8, analyzer_int8)``, and one
-    ``route_step`` tracer span with an ``analyzer_quant`` attr; both
-    carry the select form (``select_form``).
+    ``route_step`` tracer span with an ``analyzer_quant`` attr.
     Returns host numpy ``route_step`` outputs plus ``tt_idx`` /
     ``dm_idx`` / ``cx`` / ``conf`` / ``task_vectors`` sliced to B.
     """
@@ -794,7 +779,6 @@ def analyze_route_step(params, cfg, tokens, emb, tt_matrix, dm_matrix,
     blk_n = 512 if np_pad % 512 == 0 else LANE
     e2_d, e2s_d, masks_d, counts_d = _catalog_pack(
         emb, tt_matrix, dm_matrix, gmask, np_pad, quant=quant)
-    select = select_form(e2_d)
     fbt = _fb_table_pack(fb_table, np_pad) if has_fb else dummy1[0]
     if has_ad:
         thp = np.pad(np.asarray(theta, np.float32)[:n],
@@ -816,14 +800,14 @@ def analyze_route_step(params, cfg, tokens, emb, tt_matrix, dm_matrix,
         fb_buckets=int(fb_buckets), k=k, r=r, n_tt=n_tt, n_dm=n_dm,
         has_fb=has_fb, has_ad=has_ad, has_load=has_load,
         use_pallas=use_pallas, blk_q=8, blk_n=blk_n,
-        interpret=interp, quant=quant, select=select)
+        interpret=interp, quant=quant)
     prof = _COST_PROFILER
     if prof is not None:
         prof.capture(("fused", qp, np_pad, (quant, aquant), 1),
                      analyze_route_step_jit, call)
     with span(tracer, "route_step", path="fused", batch=B, q_bucket=qp,
               n_bucket=np_pad, catalog_n=n, quant=quant,
-              analyzer_quant=aquant, shards=1, select=select) as sp:
+              analyzer_quant=aquant, shards=1) as sp:
         out, compiles = _count_compiles(analyze_route_step_jit, call)
         sp.set(compiles=compiles)
     _bump("route_step", compiles)
@@ -831,7 +815,7 @@ def analyze_route_step(params, cfg, tokens, emb, tt_matrix, dm_matrix,
     hook = _RECOMPILE_HOOK
     if hook is not None:
         hook({"path": "fused", "q_bucket": qp, "n_bucket": np_pad,
-              "quant": (quant, aquant), "shards": 1, "select": select,
+              "quant": (quant, aquant), "shards": 1,
               "compiles": compiles})
     if telemetry is not None:
         telemetry.record_route_step(dispatches=1, compiles=compiles)

@@ -30,26 +30,19 @@ into a single program (one device dispatch per routed batch):
 With ``use_pallas`` the kNN stage runs the Pallas ``router_topk``
 kernel (blocked MXU matmul + the in-kernel ``carry_block_topk`` carry
 update) and the fallback re-score is its own ``top_k``.  Without it
-the single-matrix form above runs as plain XLA, and its select stage
-(mask rows -> masked scores -> exact top-R -> candidate gathers) takes
-one of two forms, chosen by ``select`` from the platform the catalog
-pack lives on (``ops.select_form``); both give the same values:
-
-  * ``"cpu"`` — the XLA:CPU lowering the test suite exercises: a row
-    gather of the mask table, chunk-of-32 top-k (``_hier_topk``:
-    XLA:CPU's TopK emitter is slow per element, a max reduction is
-    not), row gathers of the catalog block, and
-    ``jax.lax.optimization_barrier`` on the big (B, N) intermediates —
-    without them XLA:CPU duplicates cheap producers (mask gathers,
-    where chains) into every consumer and the program slows ~20x;
-  * ``"tpu"`` — shaped to the (8, 128) tile, so that no catalog-sized
-    array is relaid out, padded or copied: one dynamic slice per
-    mask row (XLA:TPU lowers the row gather by slicing the whole
-    table into column blocks), chunk maxima over whole 128-lane tiles
-    (a 32-wide chunk pads 4x to the lane width and is copied through
-    two ``while`` loops), candidate rows read as columns of the block
-    in its stored catalog-minor layout (a row gather relays the whole
-    block out row-major first), and no barriers.
+the single-matrix form above runs as plain XLA, on every platform.
+Its select stage (mask rows -> masked scores -> exact top-R ->
+candidate gathers) is shaped so that XLA:TPU relays out, pads or
+copies nothing of catalog size: one dynamic slice per mask row (a row
+gather of the table is lowered by slicing the whole table into column
+blocks), one ``lax.top_k`` over the whole row (``_hier_topk``'s
+32-wide chunks pad 4x to the 128-lane tile and are copied through two
+``while`` loops), and one gather of whole candidate rows of the
+catalog block (gathering a half slices, and so relays out, all of
+it).  ``jax.lax.optimization_barrier`` pins the big (B, N)
+intermediates: without it XLA:CPU duplicates cheap producers (mask
+gathers, where chains) into every consumer; XLA:TPU compiles the same
+program with or without it.
 
 All shapes are static per (Q bucket, padded catalog) pair — ``ops.py``
 pads Q up to power-of-two buckets and N to the catalog's 128-aligned
@@ -99,8 +92,6 @@ from repro.kernels.router_topk import (Q8_BLK_Q, router_topk_pallas,
                                        tree_merge_topk)
 
 NEG_INF = float("-inf")
-LANE = 128      # TPU lane width: the catalog's capacity step (ops.n_bucket)
-                # and the TPU form's top-k chunk, which it therefore divides
 
 
 def _f32_matmuls(fn):
@@ -121,9 +112,8 @@ def _hier_topk(z, kk: int, chunk: int = 32):
     """Exact top-kk of (B, Np) via chunk-max pruning.
 
     XLA:CPU's TopK emitter costs ~O(elements) at a poor rate, while a
-    plain max reduction is fast (the TPU select form passes one
-    128-lane tile, ``LANE``, as the chunk).  So: per-chunk maxima (one
-    cheap reduce), keep the kk chunks with the largest maxima — any true
+    plain max reduction is fast.  So: per-chunk maxima (one cheap
+    reduce), keep the kk chunks with the largest maxima — any true
     top-kk element must live in one of them, since each excluded
     chunk's max is dominated by kk other chunks' maxima — gather those
     chunks, and run the expensive TopK over kk*chunk columns instead
@@ -257,20 +247,12 @@ def _quant_operands(e2, e2s, M: int):
     return (e2[:, :M], e2s[:, 0], e2[:, M:], e2s[:, 1])
 
 
-def _mask_rows_tpu(masks_table, zi):
+def _mask_rows(masks_table, zi):
     """``masks_table[zi]`` as one dynamic slice per query row, so the
     program reads B rows of the table and not all of them."""
     return jnp.concatenate([
         jax.lax.dynamic_slice_in_dim(masks_table, zi[b], 1)
         for b in range(zi.shape[0])])
-
-
-def _take_rows_tpu(x, idx):
-    """``x[idx]`` for a catalog-major (Np, d) block with d < 128, which
-    XLA:TPU stores catalog axis minor: gathering columns of ``x.T`` (a
-    bitcast of that layout) reads only the indexed tiles."""
-    return jnp.take(x.T, idx.reshape(-1), axis=1, mode="clip").T.reshape(
-        *idx.shape, x.shape[1])
 
 
 # ----------------------------------------------------------------------
@@ -283,7 +265,7 @@ def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
                      n_tt: int, n_dm: int, has_fb: bool,
                      has_ad: bool, has_load: bool, use_pallas: bool,
                      blk_q: int, blk_n: int, interpret: bool,
-                     quant: bool = False, select: str = "cpu"):
+                     quant: bool = False):
     """Traced body of ``route_step_jit`` (same signature, un-jitted) —
     split out so ``analyze_step.analyze_route_step_jit`` can inline the
     whole routing step after the analyzer encoder inside ONE program
@@ -313,9 +295,7 @@ def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
     (Np, Dc*Dc) bandit posterior (LinUCB; dummies when ``has_ad``
     False); lpen (Np,) pre-scaled load penalty (dummy when
     ``has_load`` False); params (3,) f32 traced scalars
-    [feedback_weight, adaptive_weight, alpha]; ``select`` ("cpu" /
-    "tpu") the form of the XLA select stage (module docstring), unused
-    with ``use_pallas``.
+    [feedback_weight, adaptive_weight, alpha].
 
     Returns a dict of (Qp,)/(Qp, R) arrays with R = max(k, r):
     ``model_idx``, ``score``, ``stage`` (0 = primary, 1.. = fallback
@@ -394,15 +374,13 @@ def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
         cand_score = jnp.where(hp, cs, fv)
         cand_idx = jnp.where(hp, cidx, fidx_safe).astype(jnp.int32)
     else:
-        # XLA:CPU structure: primary rows rank masked COSINE (the
+        # XLA structure: primary rows rank masked COSINE (the
         # kNN), fallback rows rank their rung-masked BLEND — the two
         # matrices are disjoint per row, so ONE block-diagonal matmul
         # ([qn | 0] or [0 | W] against [embn | emb]) and ONE top_k
         # serve the kNN and the whole fallback ladder together
-        tpu = select == "tpu"
         zi = jnp.where(has_primary, ci, fi)
-        zmask = _mask_rows_tpu(masks_table, zi) if tpu \
-            else bar(masks_table[zi])                     # (B, Np)
+        zmask = bar(_mask_rows(masks_table, zi))          # (B, Np)
         if quant:
             xsel = jnp.concatenate(
                 [jnp.where(hp, q8, 0), jnp.where(hp, 0, w8)], axis=1)
@@ -418,22 +396,16 @@ def _route_step_body(e2, e2s, masks_table, counts_table, T, W, ti, di, fb,
             zsrc = xsel @ e2.T                            # (B, Np)
         if extras is not None:      # blend terms join fallback rows
             zsrc = zsrc + jnp.where(hp, 0.0, 1.0) * extras
-        z = jnp.where(zmask, zsrc, NEG_INF)
-        if tpu:
-            vals, idx = _hier_topk(z, R, chunk=LANE)
-        else:
-            vals, idx = bar(_hier_topk(bar(z), R))
+        z = bar(jnp.where(zmask, zsrc, NEG_INF))
+        vals, idx = bar(jax.lax.top_k(z, R))
         finite = vals > NEG_INF
         idx_safe = jnp.where(finite, idx, 0)
-        # candidate rows of both halves: the blend half for every lane,
-        # the unit half for lane 0 (a fallback row's similarity)
-        if tpu:
-            rows = _take_rows_tpu(e2, idx_safe)           # (B, R, 2M)
-            rows_e, rows_n = rows[..., M:], rows[:, 0, :M]
-        elif quant:
-            rows_e, rows_n = e8e[idx_safe], e8n[idx_safe[:, 0]]
-        else:
-            rows_e, rows_n = emb[idx_safe], embn[idx_safe[:, 0]]
+        # candidate rows of both halves in one gather of the whole
+        # block (gathering a half slices, and so relays out, all of it
+        # on XLA:TPU): the blend half for every lane, the unit half for
+        # lane 0 (a fallback row's similarity)
+        rows = e2[idx_safe]                               # (B, R, 2M)
+        rows_e, rows_n = rows[..., M:], rows[:, 0, :M]
         # primary candidates = the first k cosine-ranked positions;
         # their blended scores (computed at the k columns only, like
         # the staged gather) re-rank them in-program
@@ -475,7 +447,7 @@ route_step_jit = jax.jit(
     _route_step_body,
     static_argnames=("k", "r", "n_tt", "n_dm", "has_fb",
                      "has_ad", "has_load", "use_pallas", "blk_q",
-                     "blk_n", "interpret", "quant", "select"))
+                     "blk_n", "interpret", "quant"))
 
 
 # ----------------------------------------------------------------------

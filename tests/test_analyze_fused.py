@@ -113,12 +113,10 @@ def test_analyze_route_step_matches_ref(analyzer, with_fb, with_ad,
 
 @pytest.mark.parametrize("b", [6, 20])          # Qp 8 and Qp 32
 @pytest.mark.parametrize("extras", [False, True])
-def test_analyze_route_step_tpu_select_form(analyzer, monkeypatch, b,
-                                            extras):
-    """The decision cell's program with its select stage in the TPU
-    form (forced through ``ops.select_form``): the CPU form's outputs
-    bit for bit, and the oracle's, over 12 lane tiles so the chunked
-    top-k runs."""
+def test_analyze_route_step_matches_ref_over_lane_tiles(analyzer, b,
+                                                        extras):
+    """The decision cell's program, at its Q buckets (8 and 32), over
+    12 lane tiles of catalog: the oracle's outputs."""
     rng = np.random.default_rng(b)
     n, m = 1500, 8
     nt, nd = len(TASK_TYPES), len(DOMAINS)
@@ -133,23 +131,18 @@ def test_analyze_route_step_tpu_select_form(analyzer, monkeypatch, b,
         kw = dict(fb_table=rng.normal(size=(nt * nd * 4, n)).astype(
             np.float32) * 0.1, fb_weight=0.7, lpen=rng.random(n).astype(
             np.float32))
-    got = {}
-    for select in ("cpu", "tpu"):
-        monkeypatch.setattr(K, "select_form", lambda x, s=select: s)
-        got[select] = K.analyze_route_step(
-            analyzer.params, CFG, toks, emb, tt, dm, gmask, W, k=8, r=8,
-            threshold=0.08, acc_col=0, **kw)
+    got = K.analyze_route_step(
+        analyzer.params, CFG, toks, emb, tt, dm, gmask, W, k=8, r=8,
+        threshold=0.08, acc_col=0, **kw)
     want = R.analyze_route_step(
         analyzer.params, CFG, toks, emb, tt, dm, gmask, W, 8, 8,
         threshold=0.08, acc_col=0, pad_id=PAD_ID, **kw)
-    for key, v in got["tpu"].items():
-        np.testing.assert_array_equal(v, got["cpu"][key], err_msg=key)
     for key in ("model_idx", "stage", "cand_idx", "n_filtered",
                 "n_candidates"):
-        np.testing.assert_array_equal(got["tpu"][key],
-                                      np.asarray(want[key]), err_msg=key)
+        np.testing.assert_array_equal(got[key], np.asarray(want[key]),
+                                      err_msg=key)
     for key in ("score", "similarity", "cand_score"):
-        np.testing.assert_allclose(got["tpu"][key], np.asarray(want[key]),
+        np.testing.assert_allclose(got[key], np.asarray(want[key]),
                                    atol=2e-4, err_msg=key)
 
 

@@ -145,8 +145,6 @@ def test_batch_trace_tree_spans_whole_pipeline():
     assert rs["attrs"]["batch"] == 5
     assert rs["attrs"]["q_bucket"] >= 5
     assert rs["attrs"]["path"] in ("dense", "sharded", "ivf")
-    assert rs["attrs"]["select"] == (
-        "cpu" if rs["attrs"]["path"] == "dense" else None)
     assert "compiles" in rs["attrs"]
     # per-request roots are separate traces linking back to the batch
     for r in out:

@@ -67,7 +67,7 @@ def _random_problem(B, N, seed, *, with_fb=True, with_ad=True,
                     with_load=True, tt_p=0.4, dm_p=0.5, period=0):
     """A random routing problem; ``period`` > 0 repeats the first
     ``period`` catalog rows (embedding and filter columns) over the
-    whole catalog, so equal scores straddle every top-k chunk."""
+    whole catalog, so equal scores recur across every row."""
     rng = np.random.default_rng(seed)
     M = len(METRICS)
     nt, nd = len(TASK_TYPES), len(DOMAINS)
@@ -104,33 +104,21 @@ def _ref_kwargs(kw):
             for k2, v in kw.items()}
 
 
-def _forms(cases):
-    """``(id, args)`` cases for a test taking a trailing ``select``:
-    each in the CPU select form under its own id, then in the TPU form
-    as ``tpu-<id>``."""
-    return ([pytest.param(*a, "cpu", id=i) for i, a in cases]
-            + [pytest.param(*a, "tpu", id=f"tpu-{i}") for i, a in cases])
-
-
-def _force_select(monkeypatch, select):
-    """Run the dense program's select stage in ``select``'s form, which
-    ``ops.select_form`` otherwise reads from the catalog's platform."""
-    monkeypatch.setattr(K, "select_form", lambda x: select)
-
-
-@pytest.mark.parametrize("B,N,k,flags,select", _forms([
-    ("1-5-3-flags0", (1, 5, 3, (True, True, True))),  # B=1, tiny catalog
-    ("9-130-8-flags1", (9, 130, 8, (True, False, True))),  # N past a lane block
-    ("16-257-4-flags2", (16, 257, 4, (False, True, False))),  # off-by-one N
-    ("33-96-2-flags3", (33, 96, 2, (False, False, False))),  # non-pow2 batch
-    # 12 lane tiles > R, so both forms' chunked top-k runs, with 36
-    # pad columns in the last tile; Qp 8 and Qp 32
-    ("5-1500-8-flags0", (5, 1500, 8, (True, True, True))),
-    ("20-1500-8-flags0", (20, 1500, 8, (True, True, True))),
-    ("20-1500-8-flags3", (20, 1500, 8, (False, False, False))),
-]))
-def test_route_step_matches_ref(monkeypatch, B, N, k, flags, select):
-    _force_select(monkeypatch, select)
+@pytest.mark.parametrize("B,N,k,flags", [
+    pytest.param(1, 5, 3, (True, True, True), id="1-5-3-flags0"),  # B=1
+    pytest.param(9, 130, 8, (True, False, True),
+                 id="9-130-8-flags1"),  # N just past one lane block
+    pytest.param(16, 257, 4, (False, True, False),
+                 id="16-257-4-flags2"),  # off-by-one catalog
+    pytest.param(33, 96, 2, (False, False, False),
+                 id="33-96-2-flags3"),  # blend-free, non-pow2 batch
+    # 12 lane tiles, 36 pad columns in the last; Qp 8 and Qp 32
+    pytest.param(5, 1500, 8, (True, True, True), id="5-1500-8-flags0"),
+    pytest.param(20, 1500, 8, (True, True, True), id="20-1500-8-flags0"),
+    pytest.param(20, 1500, 8, (False, False, False),
+                 id="20-1500-8-flags3"),
+])
+def test_route_step_matches_ref(B, N, k, flags):
     args, kw = _random_problem(B, N, seed=B * 1000 + N,
                                with_fb=flags[0], with_ad=flags[1],
                                with_load=flags[2])
@@ -151,15 +139,14 @@ def test_route_step_matches_ref(monkeypatch, B, N, k, flags, select):
     (5, "ties", False), (20, "ties", False),
     (5, "fallback", False), (20, "fallback", True),
     (5, "extras", True), (20, "extras", True)])
-def test_tpu_select_form_matches_cpu_form_and_ref(monkeypatch, B, kind,
-                                                  quant):
-    """The TPU select form gives the CPU form's values bit for bit, and
-    the oracle's.  ``ties``: every row repeats with period 7, so equal
-    values straddle each chunk boundary; the picked indices may then
-    differ (the contract ``_hier_topk`` documents), but each names a
-    row identical to the oracle's pick.  ``fallback``: sparse filters,
-    so most rows walk the ladder.  ``extras``: feedback, bandit and
-    load terms on."""
+def test_select_stage_matches_ref(B, kind, quant):
+    """The dense program's select stage (mask rows, masked scores,
+    top-R, candidate gathers) against the oracle over 12 lane tiles.
+    ``ties``: every row repeats with period 7, so equal values fill
+    each row; a picked index may then differ from the oracle's, but
+    names a row identical to it.  ``fallback``: sparse filters, so
+    most rows walk the ladder.  ``extras``: feedback, bandit and load
+    terms on."""
     N, k = 1500, 8
     args, kw = _random_problem(
         B, N, seed=B + 31, with_fb=kind == "extras",
@@ -167,35 +154,27 @@ def test_tpu_select_form_matches_cpu_form_and_ref(monkeypatch, B, kind,
         tt_p=0.002 if kind == "fallback" else 0.4,
         dm_p=0.002 if kind == "fallback" else 0.5,
         period=7 if kind == "ties" else 0)
-    got = {}
-    for select in ("cpu", "tpu"):
-        _force_select(monkeypatch, select)
-        got[select] = K.route_step(*args, k=k, r=k, quant=quant, **kw)
+    got = K.route_step(*args, k=k, r=k, quant=quant, **kw)
     want = R.route_step(*(jnp.asarray(a) for a in args), k, k,
                         quant=quant, **_ref_kwargs(kw))
-    cpu, tpu = got["cpu"], got["tpu"]
     if kind == "fallback":
-        assert (tpu["stage"] > 0).mean() >= 0.6
-    for key in ("stage", "n_filtered", "n_candidates", "score",
-                "similarity", "cand_score"):
-        np.testing.assert_array_equal(tpu[key], cpu[key], err_msg=key)
+        assert (got["stage"] > 0).mean() >= 0.6
     for key in ("stage", "n_filtered", "n_candidates"):
-        np.testing.assert_array_equal(tpu[key], np.asarray(want[key]),
+        np.testing.assert_array_equal(got[key], np.asarray(want[key]),
                                       err_msg=key)
     for key in ("score", "similarity", "cand_score"):
-        np.testing.assert_allclose(tpu[key], np.asarray(want[key]),
+        np.testing.assert_allclose(got[key], np.asarray(want[key]),
                                    rtol=2e-5, atol=2e-5, err_msg=key)
     if kind == "ties":
         emb, ci = args[0], np.asarray(want["cand_idx"])
-        live = tpu["cand_idx"] >= 0
+        live = got["cand_idx"] >= 0
         np.testing.assert_array_equal(live, ci >= 0)
-        np.testing.assert_array_equal(emb[tpu["cand_idx"][live]],
+        np.testing.assert_array_equal(emb[got["cand_idx"][live]],
                                       emb[ci[live]])
     else:
         for key in ("model_idx", "cand_idx"):
-            np.testing.assert_array_equal(tpu[key], cpu[key])
-            np.testing.assert_array_equal(tpu[key],
-                                          np.asarray(want[key]))
+            np.testing.assert_array_equal(got[key], np.asarray(want[key]),
+                                          err_msg=key)
 
 
 def test_route_step_pallas_path_matches_jnp():
@@ -263,14 +242,13 @@ def _full_engine(n=64, seed=0, *, with_fb=True, with_ad=True,
 _FLAGS = [(False, False, False), (True, True, True)]
 
 
-@pytest.mark.parametrize("b,flags,n,select", _forms(
-    [(f"flags{i}-{b}", (b, f, 64)) for i, f in enumerate(_FLAGS)
-     for b in (1, 13)]
-    # 12 lane tiles: the chunked top-k runs; Qp 8 and Qp 32
-    + [(f"flags{i}-{b}-n1500", (b, f, 1500)) for i, f in enumerate(_FLAGS)
-       for b in (5, 20)]))
-def test_fused_matches_staged_full_blend(monkeypatch, b, flags, n, select):
-    _force_select(monkeypatch, select)
+@pytest.mark.parametrize("b,flags,n", [
+    pytest.param(b, f, 64, id=f"flags{i}-{b}")
+    for i, f in enumerate(_FLAGS) for b in (1, 13)]
+    # 12 lane tiles; Qp 8 and Qp 32
+    + [pytest.param(b, f, 1500, id=f"flags{i}-{b}-n1500")
+       for i, f in enumerate(_FLAGS) for b in (5, 20)])
+def test_fused_matches_staged_full_blend(b, flags, n):
     eng = _full_engine(n, seed=b, with_fb=flags[0], with_ad=flags[1],
                        with_load=flags[2])
     prefs, sigs = random_queries(b, seed=b + 5)
@@ -281,15 +259,6 @@ def test_fused_matches_staged_full_blend(monkeypatch, b, flags, n, select):
 
 def test_fused_matches_staged_fallback_heavy():
     """A catalog with narrow coverage forces every fallback rung."""
-    _check_fallback_heavy()
-
-
-def test_fused_matches_staged_fallback_heavy_tpu_form(monkeypatch):
-    _force_select(monkeypatch, "tpu")
-    _check_fallback_heavy()
-
-
-def _check_fallback_heavy():
     from tests.conftest import make_entry
     from repro.core.mres import MRES
     m = MRES()

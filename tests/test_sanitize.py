@@ -197,7 +197,7 @@ def test_set_recompile_hook_detach():
         assert len(events) == 1
         ev = events[0]
         assert set(ev) == {"path", "q_bucket", "n_bucket", "quant",
-                           "shards", "select", "compiles"}
+                           "shards", "compiles"}
         assert ev["path"] == "dense"
         ops.set_recompile_hook(None)
         ops.route_step(*_tiny_problem(1), k=2, r=2)

@@ -105,7 +105,7 @@ def test_route_step_compiles(one_chip, use_pallas, quant):
         o["theta"], o["ainv_flat"], o["lpen"], s((3,), jnp.float32),
         k=8, r=8, n_tt=N_TT, n_dm=N_DM, has_fb=False, has_ad=False,
         has_load=False, use_pallas=use_pallas, blk_q=8, blk_n=128,
-        interpret=False, quant=quant, select="tpu").compile()
+        interpret=False, quant=quant).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
 
 
@@ -131,37 +131,59 @@ def test_analyze_route_step_compiles_with_kernel(one_chip, quant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decision_program_select_stage_relays_out_nothing(one_chip):
-    """decide-1m-open's program (Qp 8, a 1,000,064-column catalog,
-    fp32, no Pallas) with the TPU select form: no ``while`` in the
-    entry computation, and at most 0.6 GB accessed by XLA's estimate.
-    The chunk-of-32 form reads 1.64 GB through two ``while`` copies of
-    the scores, a gather of the whole mask table and a row-major copy
-    of the catalog block."""
+def _decision_program(sharding, quant):
+    """decide-1m-open's program compiled for one described v5e: Qp 8,
+    a 1,000,064-column catalog, no Pallas; fp32 or int8 catalog."""
     from repro.core.analyzer import AnalyzerConfig, init_analyzer
     from repro.kernels.analyze_step import analyze_route_step_jit
     cfg = AnalyzerConfig()
     qp, n_cat = 8, 1_000_064
-    s = lambda *a: _sds(one_chip, *a)  # noqa: E731
-    params = _on(one_chip, jax.eval_shape(
+    s = lambda *a: _sds(sharding, *a)  # noqa: E731
+    params = _on(sharding, jax.eval_shape(
         lambda: init_analyzer(jax.random.PRNGKey(0), cfg)))
     rows = N_TT * N_DM + N_TT + 2
-    compiled = analyze_route_step_jit.lower(
+    return analyze_route_step_jit.lower(
         params, s((qp, cfg.max_len), jnp.int32), s((qp, M), jnp.float32),
         s((1,), jnp.float32), s((1, 1), jnp.float32),
-        s((n_cat, 2 * M), jnp.float32), s((1, 2), jnp.float32),
+        s((n_cat, 2 * M), jnp.int8 if quant else jnp.float32),
+        s((n_cat, 2) if quant else (1, 2), jnp.float32),
         s((rows, n_cat), jnp.bool_), s((rows,), jnp.int32),
         s((1, 1), jnp.float32), s((1, 1), jnp.float32),
         s((1,), jnp.float32), s((3,), jnp.float32), cfg=cfg, acc_col=0,
         use_complexity=True, fb_buckets=4, k=8, r=8, n_tt=N_TT,
         n_dm=N_DM, has_fb=False, has_ad=False, has_load=False,
         use_pallas=False, blk_q=8, blk_n=128, interpret=False,
-        quant=False, select="tpu").compile()
+        quant=quant).compile()
+
+
+def _entry_bytes(compiled):
+    """(entry computation's HLO text, XLA's bytes-accessed estimate)."""
     text = compiled.as_text()
-    assert "while(" not in text[text.index("ENTRY"):]
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
-    assert cost["bytes accessed"] <= 0.6e9, cost["bytes accessed"]
+    return text[text.index("ENTRY"):], cost["bytes accessed"]
+
+
+def test_decision_program_select_stage_relays_out_nothing(one_chip):
+    """decide-1m-open's program: no ``while`` in the entry computation,
+    and at most 0.6 GB accessed by XLA's estimate.  A chunk-of-32
+    top-k, a row gather of the mask table and gathers from halves of
+    the catalog block read 1.64 GB, through two ``while`` copies of
+    the scores, a gather of the whole mask table and a row-major copy
+    of the catalog block."""
+    entry, nbytes = _entry_bytes(_decision_program(one_chip, False))
+    assert "while(" not in entry
+    assert nbytes <= 0.6e9, nbytes
+
+
+def test_decision_program_int8_catalog_reads_under_600mb(one_chip):
+    """The same program over the int8 catalog block: no ``while`` and
+    at most 0.6 GB accessed (1.90 GB with the chunk-of-32 top-k).  One
+    row-major copy of the 16-MB int8 block remains, for the candidate
+    gather: XLA:TPU makes it for a row or a column gather alike."""
+    entry, nbytes = _entry_bytes(_decision_program(one_chip, True))
+    assert "while(" not in entry
+    assert nbytes <= 0.6e9, nbytes
 
 
 def test_bandit_update_compiles(one_chip):
